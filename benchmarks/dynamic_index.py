@@ -55,6 +55,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from benchmarks.common import BENCH_LOOKUPS, BENCH_N, emit, ns_per_item
+from repro.compile_cache import enable_compile_cache
 from repro.core import RMIConfig, build_rmi, compile_lookup, make_keyset
 from repro.data import gen_weblogs
 from repro.index_service import (
@@ -815,6 +816,7 @@ def fault_sweep(raw=None, ks=None) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     raw = gen_weblogs(BENCH_N)
     ks = make_keyset(raw)

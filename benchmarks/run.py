@@ -11,6 +11,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     fast = os.environ.get("LIX_BENCH_FAST", "0") == "1"
     from benchmarks import (
         fig4_maps, fig5_weblog, fig6_lognormal, fig7_strings, fig8_search,

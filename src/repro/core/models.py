@@ -118,14 +118,27 @@ def mlp_init(spec: MLPSpec, key: jax.Array) -> Dict[str, jax.Array]:
     return params
 
 
+def dense_f32(h: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+    """``h @ w + b`` as broadcast f32 multiply-adds accumulated in input
+    order.  A TPU runs a float32 matmul at reduced precision by default
+    and a Pallas kernel cannot use the MXU at these widths, so the
+    stage-0 model is evaluated this way everywhere — build, XLA lookups
+    and the kernels (`kernels.rmi_lookup._stage0`) then agree bit for
+    bit on every backend."""
+    acc = h[:, 0:1] * w[0:1, :]
+    for k in range(1, w.shape[0]):
+        acc = acc + h[:, k:k + 1] * w[k:k + 1, :]
+    return acc + b[None, :]
+
+
 def mlp_apply(params: Dict[str, jax.Array], x: jax.Array) -> jax.Array:
     """x: (B,) scalar keys or (B, D) vector keys -> (B,) predictions."""
     h = x[:, None] if x.ndim == 1 else x
     n_layers = len(params) // 2
     for i in range(n_layers):
-        h = h @ params[f"w{i}"] + params[f"b{i}"]
+        h = dense_f32(h, params[f"w{i}"], params[f"b{i}"])
         if i < n_layers - 1:
-            h = jax.nn.relu(h)
+            h = jnp.maximum(h, 0.0)
     return h[:, 0]
 
 

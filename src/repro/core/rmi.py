@@ -267,18 +267,28 @@ def _finalize_rmi(
     parameters were obtained (cold fit or warm reuse in `refit_rmi`).
     """
     m = config.num_leaves
+    prod = leaf_w[seg] * norm
     if in_dim == 1:
-        pred1 = leaf_w[seg] * norm + leaf_b[seg]
+        pred1 = prod + leaf_b[seg]
     else:
-        pred1 = np.sum(leaf_w[seg] * norm, axis=-1) + leaf_b[seg]
+        pred1 = np.sum(prod, axis=-1) + leaf_b[seg]
+        prod = np.sum(np.abs(prod), axis=-1)
     pred1 = np.clip(pred1.astype(np.float32), 0.0, float(n - 1))
 
     # ---- residual bounds (the B-Tree-strength guarantee) -------------------
-    resid = y - pred1
+    # A lookup may evaluate the leaf FMA with one rounding (a fused
+    # multiply-add: XLA on CPU) or two (NumPy here, the TPU), which can
+    # differ by half an ulp of the product plus an ulp of the position,
+    # and adds the bound to the position in f32 (another half ulp once
+    # positions pass 2^24).  Widening each key's residual by that slack
+    # keeps every stored key inside its window on every backend.
+    slack = (np.spacing(np.abs(prod).astype(np.float32))
+             + 2 * np.spacing(np.float32(n)))
+    resid = np.arange(n, dtype=np.float64) - pred1
     err_lo = np.zeros(m, np.float32)
     err_hi = np.zeros(m, np.float32)
-    np.minimum.at(err_lo, seg, np.floor(resid).astype(np.float32))
-    np.maximum.at(err_hi, seg, np.ceil(resid).astype(np.float32))
+    np.minimum.at(err_lo, seg, np.floor(resid - slack).astype(np.float32))
+    np.maximum.at(err_hi, seg, np.ceil(resid + slack).astype(np.float32))
     # σ per leaf
     sums = np.bincount(seg, weights=resid, minlength=m)
     sqs = np.bincount(seg, weights=resid * resid, minlength=m)

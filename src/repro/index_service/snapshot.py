@@ -25,6 +25,7 @@ RMI window guarantee).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import re
 import threading
@@ -94,6 +95,76 @@ MERGED_STRATEGIES: Tuple[str, ...] = (
 # small snapshots fall back to fewer sub-shards so every chunk keeps
 # >= 2 distinct float32 keys
 SHARDED_FUSED_SUBSHARDS = 4
+
+
+# device arrays of the snapshot-level sub-shard plan (jit arguments)
+_SHARDED_PLAN_ARRAYS = (
+    "stage0", "leaf_w", "leaf_b", "err_lo", "err_hi", "keys", "shard_n",
+    "shard_m", "shard_ratio", "starts", "base_off",
+)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("strategy", "n", "num_leaves", "max_window"))
+def _xla_base(q, tree, base_norm, *, strategy, n, num_leaves, max_window):
+    return rmi_lookup(tree, base_norm, q, n=n, num_leaves=num_leaves,
+                      max_window=max_window, strategy=strategy)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("strategy", "n", "num_leaves", "max_window"))
+def _xla_merged(q, dkeys, dprefix, tree, base_norm, *, strategy, n,
+                num_leaves, max_window):
+    b = rmi_lookup(tree, base_norm, q, n=n, num_leaves=num_leaves,
+                   max_window=max_window, strategy=strategy)
+    return b, b + dprefix[search_lib.lower_bound_full(dkeys, q)]
+
+
+@functools.partial(
+    jax.jit, static_argnames=("n", "num_leaves", "max_window"))
+def _xla_fused_merged(q, s0, leaf_w, leaf_b, err_lo, err_hi, base_norm,
+                      dkeys, dprefix, *, n, num_leaves, max_window):
+    return kernels_ref.rmi_merged_lookup_reference(
+        q, s0, leaf_w, leaf_b, err_lo, err_hi, base_norm, dkeys, dprefix,
+        n=n, num_leaves=num_leaves, max_window=max_window,
+    )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("hidden", "n", "num_leaves", "max_window"))
+def _pallas_merged(q, s0, leaf_w, leaf_b, err_lo, err_hi, base_norm, dkeys,
+                   dprefix, *, hidden, n, num_leaves, max_window):
+    b = rmi_lookup_pallas(
+        q, s0, leaf_w, leaf_b, err_lo, err_hi, base_norm, hidden=hidden,
+        n=n, num_leaves=num_leaves, max_window=max_window,
+    )
+    return b, b + dprefix[search_lib.lower_bound_full(dkeys, q)]
+
+
+@functools.partial(jax.jit, static_argnames=("hidden", "max_window"))
+def _sharded_merged(q, dkeys, dprefix, plan, *, hidden, max_window):
+    """Route -> every sub-shard row runs its bounded search in one
+    grid-over-shards pallas_call -> prefix-offset reassembly.  The delta
+    stays global at snapshot level (one sorted array), so each row
+    searches the same broadcast delta and merged offsets == base
+    offsets; per-shard deltas enter at the service level
+    (ShardedIndexService).  The pallas call is made directly, not
+    through the public op: the closure that calls this program is the
+    ONE dispatch record per entry."""
+    shard = jnp.searchsorted(plan["starts"], q, side="right").astype(
+        jnp.int32)
+    s = plan["keys"].shape[0]
+    lb, ct = rmi_sharded_merged_lookup_pallas(
+        jnp.broadcast_to(q, (s, q.shape[0])), plan["stage0"],
+        plan["leaf_w"], plan["leaf_b"], plan["err_lo"], plan["err_hi"],
+        plan["keys"], jnp.broadcast_to(dkeys, (s, dkeys.shape[0])),
+        jnp.broadcast_to(dprefix, (s, dprefix.shape[0])), plan["shard_n"],
+        plan["shard_m"], plan["shard_ratio"], hidden=hidden,
+        max_window=max_window,
+    )
+    return kernels_ops.sharded_reassemble(
+        lb, ct, shard, plan["base_off"], plan["base_off"]
+    )
 
 
 def validate_strategy(strategy: str) -> str:
@@ -223,89 +294,45 @@ class IndexSnapshot:
         prefix gather — as two dispatches (`binary`/`biased`/
         `quaternary`/`pallas`) or one fused kernel (`pallas_fused`,
         with `xla_fused` its bit-identical XLA fallback); see
-        MERGED_STRATEGIES.  Retraces per (snapshot, delta capacity
+        MERGED_STRATEGIES.  Retraces per (snapshot size, delta capacity
         bucket) — `combine_for_device` pads the delta to power-of-two
-        buckets so individual writes never retrace.
+        buckets so individual writes never retrace.  The snapshot's
+        device arrays enter the programs as arguments: a closed-over
+        array would be baked into the executable as a constant.
         """
         validate_strategy(strategy)
         fn = self._compiled.get(strategy)
         if fn is None:
-            base_norm = jnp.asarray(self.keys.norm)
-            n, m, w = self.index.n, self.index.num_leaves, self.index.max_window
-            if strategy in ("pallas_fused", "xla_fused", "pallas"):
-                s0, arrs, hidden = self._kernel_closure_args()
+            base_norm = self._device_base()[0]
+            idx = self.index
+            static = dict(n=idx.n, num_leaves=idx.num_leaves,
+                          max_window=idx.max_window)
             if strategy == "sharded_fused":
                 plan = self._sharded_plan()
-                num_shards = plan["S"]
+                arrays = {k: plan[k] for k in _SHARDED_PLAN_ARRAYS}
 
-                @jax.jit
                 def merged(q, dkeys, dprefix):
-                    # route -> every shard row runs its bounded search in
-                    # one grid-over-shards pallas_call -> prefix-offset
-                    # reassembly.  The delta stays global at snapshot
-                    # level (one sorted array), so each row searches the
-                    # same broadcast delta and merged offsets == base
-                    # offsets; per-shard deltas enter at the service
-                    # level (ShardedIndexService).
-                    shard = jnp.searchsorted(
-                        plan["starts"], q, side="right"
-                    ).astype(jnp.int32)
-                    qs = jnp.broadcast_to(q, (num_shards, q.shape[0]))
-                    dk = jnp.broadcast_to(
-                        dkeys, (num_shards, dkeys.shape[0]))
-                    dp = jnp.broadcast_to(
-                        dprefix, (num_shards, dprefix.shape[0]))
-                    # the pallas call directly (not the public op):
-                    # inside this outer jit the op's boundary-side
-                    # dispatch accounting would fire at trace time only
-                    # — the closure wrapper below is the ONE record per
-                    # program entry
-                    lb, ct = rmi_sharded_merged_lookup_pallas(
-                        qs, plan["stage0"], plan["leaf_w"], plan["leaf_b"],
-                        plan["err_lo"], plan["err_hi"], plan["keys"],
-                        dk, dp, plan["shard_n"], plan["shard_m"],
-                        plan["shard_ratio"],
-                        hidden=plan["hidden"],
+                    return _sharded_merged(
+                        q, dkeys, dprefix, arrays, hidden=plan["hidden"],
                         max_window=plan["max_window"],
                     )
-                    return kernels_ops.sharded_reassemble(
-                        lb, ct, shard, plan["base_off"], plan["base_off"]
-                    )
-            elif strategy == "pallas_fused":
-                def merged(q, dkeys, dprefix):
-                    # rmi_merged_lookup_pallas is itself jitted (static
-                    # shape args) — one dispatch, two outputs
-                    return rmi_merged_lookup_pallas(
-                        q, s0, *arrs, base_norm, dkeys, dprefix,
-                        hidden=hidden, n=n, num_leaves=m, max_window=w,
-                    )
-            elif strategy == "xla_fused":
-                @jax.jit
-                def merged(q, dkeys, dprefix):
-                    return kernels_ref.rmi_merged_lookup_reference(
-                        q, s0, *arrs, base_norm, dkeys, dprefix,
-                        n=n, num_leaves=m, max_window=w,
-                    )
-            elif strategy == "pallas":
-                @jax.jit
-                def merged(q, dkeys, dprefix):
-                    b = rmi_lookup_pallas(
-                        q, s0, *arrs, base_norm,
-                        hidden=hidden, n=n, num_leaves=m, max_window=w,
-                    )
-                    lb = search_lib.lower_bound_full(dkeys, q)
-                    return b, b + dprefix[lb]
-            else:
-                tree = self.index.as_pytree()
+            elif strategy in ("pallas_fused", "xla_fused", "pallas"):
+                s0, arrs, hidden = self._kernel_closure_args()
+                impl = {"pallas_fused": rmi_merged_lookup_pallas,
+                        "xla_fused": _xla_fused_merged,
+                        "pallas": _pallas_merged}[strategy]
+                if strategy != "xla_fused":
+                    static["hidden"] = hidden
 
-                @jax.jit
                 def merged(q, dkeys, dprefix):
-                    b = rmi_lookup(
-                        tree, base_norm, q, n=n, num_leaves=m, max_window=w,
-                        strategy=strategy,
-                    )
-                    lb = search_lib.lower_bound_full(dkeys, q)
-                    return b, b + dprefix[lb]
+                    return impl(q, s0, *arrs, base_norm, dkeys, dprefix,
+                                **static)
+            else:
+                tree = idx.as_pytree()
+
+                def merged(q, dkeys, dprefix):
+                    return _xla_merged(q, dkeys, dprefix, tree, base_norm,
+                                       strategy=strategy, **static)
 
             inner = merged
             kernel = strategy in KERNEL_STRATEGIES
@@ -423,8 +450,10 @@ class IndexSnapshot:
         key = f"base:{alias.get(strategy, strategy)}"
         fn = self._compiled.get(key)
         if fn is None:
-            base_norm = jnp.asarray(self.keys.norm)
-            n, m, w = self.index.n, self.index.num_leaves, self.index.max_window
+            base_norm = self._device_base()[0]
+            idx = self.index
+            static = dict(n=idx.n, num_leaves=idx.num_leaves,
+                          max_window=idx.max_window)
             if strategy == "sharded_fused":
                 # the sharded base search IS the merged path with
                 # nothing staged: reuse its compiled closure with an
@@ -440,19 +469,15 @@ class IndexSnapshot:
 
                 def base(q):
                     return rmi_lookup_pallas(
-                        q, s0, *arrs, base_norm,
-                        hidden=hidden, n=n, num_leaves=m, max_window=w,
+                        q, s0, *arrs, base_norm, hidden=hidden, **static
                     )
             else:
                 xla_strategy = "binary" if strategy == "xla_fused" else strategy
-                tree = self.index.as_pytree()
+                tree = idx.as_pytree()
 
-                @jax.jit
                 def base(q):
-                    return rmi_lookup(
-                        tree, base_norm, q, n=n, num_leaves=m, max_window=w,
-                        strategy=xla_strategy,
-                    )
+                    return _xla_base(q, tree, base_norm,
+                                     strategy=xla_strategy, **static)
 
             if strategy != "sharded_fused":
                 # sharded_fused delegates to the (already counted)
@@ -503,6 +528,7 @@ class IndexSnapshot:
         miss = ~in_base
         if miss.any():  # absent keys have no window guarantee: exact fallback
             i[miss] = np.searchsorted(raw, q[miss], side="left")
+            in_base[miss] = raw[np.minimum(i[miss], n - 1)] == q[miss]
         return i, in_base
 
     # ---- persistence -----------------------------------------------------

@@ -9,10 +9,13 @@ pre-folded to uint32 on the host (strings: FNV; ints: mix64 fold).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _mix32(h, seed: int):
@@ -49,7 +52,7 @@ def bloom_probe_pallas(
     num_bits: int,
     k: int,
     block_q: int = 2048,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     b = queries_u32.shape[0]
     bq = min(block_q, b)
@@ -65,6 +68,6 @@ def bloom_probe_pallas(
         ],
         out_specs=pl.BlockSpec((bq,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((padded,), jnp.bool_),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(queries_u32, words)
     return out[:b]

@@ -14,17 +14,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU compiler params are harmless to omit under interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -88,7 +85,7 @@ def flash_attention(
     causal: bool = True,
     blk_q: int = 128,
     blk_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     b, hq, s, d = q.shape
     hkv = k.shape[1]
@@ -107,8 +104,6 @@ def flash_attention(
         pltpu.VMEM((blk_q, d), jnp.float32),
         pltpu.VMEM((blk_q, 1), jnp.float32),
         pltpu.VMEM((blk_q, 1), jnp.float32),
-    ] if _HAS_PLTPU else [
-        pl.MemorySpace.ANY((blk_q, d), jnp.float32),  # pragma: no cover
     ]
 
     return pl.pallas_call(
@@ -130,5 +125,5 @@ def flash_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
         scratch_shapes=scratch,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -9,10 +9,13 @@ fixed trip count — all VMEM-resident gathers.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _hash_kernel(
@@ -64,7 +67,7 @@ def hash_probe_pallas(
     num_slots: int,
     trips: int,
     block_q: int = 2048,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     b = q.shape[0]
     bq = min(block_q, b)
@@ -83,6 +86,6 @@ def hash_probe_pallas(
                              slot_next, ovf_key, ovf_next)],
         out_specs=pl.BlockSpec((bq,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((padded,), jnp.bool_),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, s0_w, s0_b, leaf_w, leaf_b, slot_key, slot_next, ovf_key, ovf_next)
     return out[:b]

@@ -2,10 +2,9 @@
 
 Each op picks the kernel when it applies (shape/platform) and falls
 back to the pure-jnp reference otherwise; callers never touch
-pallas_call directly.  The RMI lookup ops take `interpret=None` and
-auto-select interpret mode off-TPU (`rmi_lookup.default_interpret`);
-the older ops still default `interpret=True` for this CPU container,
-flipped to False by the TPU launcher.
+pallas_call directly.  Every op takes ``interpret=None``: Mosaic on a
+TPU, Pallas interpret mode on any other backend
+(`repro.kernels.resolve_interpret`).
 """
 
 from __future__ import annotations
@@ -19,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax._src import compiler as _jax_compiler
+
 from repro import faults
 from repro.kernels import ref
 from repro.obs import metrics as obs_metrics
@@ -29,6 +30,7 @@ from repro.kernels.hash_probe import hash_probe_pallas
 from repro.kernels.rmi_lookup import (
     _merged_rank_from_prefix,
     _search_steps,
+    _xla,
     rmi_lookup_pallas,
     rmi_merged_lookup_pallas,
     rmi_scan_page_pallas,
@@ -178,9 +180,12 @@ def _shape(x):
 # kernel -> fallback strategy failover
 # ---------------------------------------------------------------------------
 # Every Pallas op below has a bit-identical XLA fallback one branch
-# away; a kernel that RAISES (driver regression, lowering bug, an
-# injected ``kernel.dispatch`` fault) must not take the read path down
-# with it.  Policy, per (op, strategy):
+# away; a kernel that RAISES WHILE IT RUNS (a device or runtime error,
+# an injected ``kernel.dispatch`` fault) must not take the read path
+# down with it.  Errors raised while the kernel is traced, lowered or
+# compiled are deterministic — a retry cannot heal them, and rerouting
+# would hide that the kernel never ran — so they propagate
+# (`_is_build_error`).  Policy for run-time errors, per (op, strategy):
 #
 #   * a healthy kernel that raises is retried ONCE (transient faults
 #     heal invisibly), and a second failure stickily reroutes the pair
@@ -238,13 +243,38 @@ def reset_failover() -> None:
         _FAILOVER.clear()
 
 
+def _tag_compile_error(err):
+    """XLA compile-error hook: mark the error so the failover can tell
+    a compile failure from a run-time one (both are JaxRuntimeError)."""
+    err.lix_compile_error = True
+    return None  # keep the original error
+
+
+_jax_compiler.register_xla_runtime_error_handler(_tag_compile_error)
+
+
+def _is_build_error(err: Exception) -> bool:
+    """True for errors from tracing, lowering or compiling a kernel:
+    Python exceptions other than RuntimeError (ValueError, TypeError,
+    Mosaic and lowering errors), NotImplementedError (an unsupported
+    lowering), and compile errors tagged by `_tag_compile_error`.
+    Run-time errors — JaxRuntimeError from an execution, the injected
+    fault — are RuntimeErrors and fail over."""
+    return (
+        not isinstance(err, RuntimeError)
+        or isinstance(err, NotImplementedError)
+        or getattr(err, "lix_compile_error", False)
+    )
+
+
 def run_with_failover(op: str, strategy, kernel_fn, fallback_fn):
     """Run ``kernel_fn`` under the retry-once + sticky-failover policy,
-    rerouting to ``fallback_fn`` (bit-identical results) on failure.
-    Both callables own their dispatch_span, so attribution stays honest
-    about which program actually ran.  Fallback errors propagate — with
-    the kernel already out of the picture there is nothing left to fail
-    over to."""
+    rerouting to ``fallback_fn`` (bit-identical results) when the
+    kernel fails while it runs.  A kernel that cannot be built raises
+    here instead (`_is_build_error`).  Both callables own their
+    dispatch_span, so attribution stays honest about which program
+    actually ran.  Fallback errors propagate — with the kernel already
+    out of the picture there is nothing left to fail over to."""
     st = _failover_state(op, strategy)
     probe = False
     if st.disabled:
@@ -260,6 +290,8 @@ def run_with_failover(op: str, strategy, kernel_fn, fallback_fn):
             faults.maybe("kernel.dispatch")
             out = kernel_fn()
         except Exception as e:
+            if _is_build_error(e):
+                raise
             reg.counter("kernel_failover.errors").add(1)
             obs_trace.instant(
                 "kernel.error", cat="fault", op=op,
@@ -769,7 +801,8 @@ def _sharded_scan_jit(
     # ownership offsets (same program, no host round-trip)
     def rank_one(base, lp, ins):
         return _merged_rank_from_prefix(
-            bounds, base, lp, ins, steps=steps, isteps=isteps
+            bounds, _xla(base), _xla(lp), _xla(ins), steps=steps,
+            isteps=isteps,
         )
 
     lr = jax.vmap(rank_one)(base_keys, live_prefix, ins_keys)  # (S, 2)
@@ -882,7 +915,7 @@ def _sharded_routed_jit(
     return sharded_reassemble(lb, ct, shard_of, base_off, merged_off)
 
 
-def bloom_probe_op(bf, queries_u32, *, interpret=True):
+def bloom_probe_op(bf, queries_u32, *, interpret=None):
     """Batched Bloom probe via kernel.  `bf` is a core.BloomFilter."""
     return bloom_probe_pallas(
         jnp.asarray(queries_u32),
@@ -893,7 +926,7 @@ def bloom_probe_op(bf, queries_u32, *, interpret=True):
     )
 
 
-def hash_probe_op(hm, index, keys, q_raw, *, interpret=True):
+def hash_probe_op(hm, index, keys, q_raw, *, interpret=None):
     """Batched hash-model probe.  `hm` HashMap, `index` linear-stage RMI."""
     kn = keys.normalize(q_raw)
     slot_key_norm = keys.normalize(hm.slot_key)  # NaN-safe: NaN != q
@@ -916,7 +949,7 @@ def hash_probe_op(hm, index, keys, q_raw, *, interpret=True):
     )
 
 
-def attention_op(q, k, v, *, causal=True, use_kernel=True, interpret=True,
+def attention_op(q, k, v, *, causal=True, use_kernel=True, interpret=None,
                  blk_q=128, blk_k=128):
     """GQA attention: flash kernel when shapes tile; reference otherwise."""
     s = q.shape[2]

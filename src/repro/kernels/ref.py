@@ -7,13 +7,13 @@ assert_allclose against these.
 
 from __future__ import annotations
 
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import search as search_lib
+from repro.core.models import dense_f32
 from repro.kernels import rmi_lookup as rmi_lookup_lib
 
 
@@ -26,7 +26,7 @@ def _rmi_predict_flat(
     h = q[:, None]
     nl = len(stage0) // 2
     for i in range(nl):
-        h = h @ stage0[2 * i] + stage0[2 * i + 1][None, :]
+        h = dense_f32(h, stage0[2 * i], stage0[2 * i + 1])
         if i < nl - 1:
             h = jnp.maximum(h, 0.0)
     p0 = h[:, 0]
@@ -121,18 +121,22 @@ def rmi_sharded_merged_lookup_reference(
     sharing the body is what makes this a drop-in fallback rather than
     a second implementation to keep in sync.
     """
+    hidden = tuple(int(w.shape[-1]) for w in stage0[:-2:2])
     steps = rmi_lookup_lib._search_steps(max_window)
     dsteps = rmi_lookup_lib._search_steps(delta_keys.shape[1])
-    body = functools.partial(
-        rmi_lookup_lib._sharded_shard_body, steps=steps, dsteps=dsteps
-    )
 
-    def one_shard(q_s, params_s, lw, lb, elo, ehi, keys, dk, dp, n, m, ratio):
-        return body(q_s, params_s, lw, lb, elo, ehi, keys, dk, dp, n, m, ratio)
+    def one_shard(q_s, params, *rest):
+        arrays, (n, m, ratio) = rest[:-3], rest[-3:]
+        return rmi_lookup_lib._shard_lookup(
+            q_s, lambda off: params[off], hidden,
+            *(rmi_lookup_lib._xla(a) for a in arrays), n, m, ratio,
+            steps=steps, dsteps=dsteps,
+        )
 
     return jax.vmap(one_shard)(
-        q, tuple(stage0), leaf_w, leaf_b, err_lo, err_hi, sorted_keys,
-        delta_keys, delta_prefix, shard_n, shard_m, shard_ratio,
+        q, rmi_lookup_lib._flat_params(stage0), leaf_w, leaf_b, err_lo,
+        err_hi, sorted_keys, delta_keys, delta_prefix, shard_n, shard_m,
+        shard_ratio,
     )
 
 
@@ -161,9 +165,10 @@ def rmi_scan_page_reference(
     t = starts.astype(jnp.int32)[:, None] + jax.lax.broadcasted_iota(
         jnp.int32, (1, page_size), 1
     )
+    flats = [rmi_lookup_lib._xla(a) for a in
+             (base_keys, base_vals, ins_keys, ins_vals, del_pos)]
     return rmi_lookup_lib._scan_page_body(
-        t, base_keys, base_vals, ins_keys, ins_vals, del_pos, end_rank[0],
-        steps=steps, isteps=isteps, dsteps=dsteps,
+        t, *flats, end_rank[0], steps=steps, isteps=isteps, dsteps=dsteps,
     )
 
 
@@ -189,6 +194,10 @@ def rmi_scan_range_reference(
     isteps = rmi_lookup_lib._search_steps(ins_keys.shape[0])
     psteps = rmi_lookup_lib._search_steps(base_keys.shape[0] + 1)
     msteps = rmi_lookup_lib._search_steps(ins_rank.shape[0])
+    base_keys, base_vals, live_prefix, ins_keys, ins_vals, ins_rank = (
+        rmi_lookup_lib._xla(a) for a in
+        (base_keys, base_vals, live_prefix, ins_keys, ins_vals, ins_rank)
+    )
     r = rmi_lookup_lib._merged_rank_from_prefix(
         bounds, base_keys, live_prefix, ins_keys,
         steps=steps, isteps=isteps,
@@ -236,7 +245,9 @@ def rmi_sharded_scan_page_reference(
         owner = (t_rel >= olo) & (t_rel < ohi)
         t_local = l0 + t_rel - olo
         return rmi_lookup_lib._scan_rows_from_index(
-            t_local, owner, base, bvals, lp, ins, ivals, irank,
+            t_local, owner,
+            *(rmi_lookup_lib._xla(a)
+              for a in (base, bvals, lp, ins, ivals, irank)),
             psteps=psteps, msteps=msteps,
         )
 

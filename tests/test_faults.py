@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro import faults
+from repro.core import RMIConfig, build_rmi, make_keyset
 from repro.distributed.fault_tolerance import (
     CheckpointCorrupt,
     CheckpointManager,
@@ -400,6 +401,44 @@ def test_failover_retries_once_then_sticks_then_recovers():
                                                fallback))
     assert "kk" in outs
     assert not kernels_ops.failover_summary()["t_op:pallas"]["disabled"]
+    kernels_ops.reset_failover()
+
+
+def test_kernel_build_error_raises_but_injected_fault_fails_over():
+    """A kernel that cannot be lowered (here: Mosaic lowering requested
+    on a backend without it) raises from the op instead of rerouting —
+    a retry cannot heal it, and the fallback would hide that the kernel
+    never ran.  The injected run-time fault still fails over to the
+    bit-identical fallback and recovers on the re-probe cadence."""
+    kernels_ops.reset_failover()
+    ks = make_keyset(_keys(2048))
+    idx = build_rmi(ks, RMIConfig(num_leaves=32, stage0_hidden=(),
+                                  stage0_train_steps=0))
+    q = jnp.asarray(ks.norm[::7])
+    dk = jnp.full((64,), jnp.inf, jnp.float32)
+    dp = jnp.zeros((65,), jnp.int32)
+
+    def op(**kw):
+        return kernels_ops.rmi_merged_lookup_op(idx, ks.norm, q, dk, dp,
+                                                **kw)
+
+    want = [np.asarray(a) for a in op(use_kernel=False)]
+    with pytest.raises(ValueError, match="interpret"):
+        op(interpret=False)  # Mosaic lowering on the CPU backend
+    st = kernels_ops.failover_summary()["rmi_merged_lookup:pallas_fused"]
+    assert not st["disabled"] and st["fallback_calls"] == 0
+
+    with faults.inject(faults.FaultSchedule({"kernel.dispatch": 2})) as s:
+        got = op()
+    assert s.fired["kernel.dispatch"] == 2
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), w)
+    assert kernels_ops.failover_summary()[
+        "rmi_merged_lookup:pallas_fused"]["disabled"]
+    for _ in range(kernels_ops.FAILOVER_REPROBE_EVERY):
+        op()
+    assert not kernels_ops.failover_summary()[
+        "rmi_merged_lookup:pallas_fused"]["disabled"]
     kernels_ops.reset_failover()
 
 
