@@ -1,0 +1,8 @@
+"""Executables built or loaded from the compile cache inside a window
+that served scans (the target is 0: warm-up covers every shape)."""
+
+
+def read(rec):
+    if not rec["service"]["scan_batch"]["calls"]:
+        return None
+    return rec["compiles_in_window"]
