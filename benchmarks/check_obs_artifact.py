@@ -9,8 +9,8 @@ merging as often as single-level fails the gate), the faults section
 (the chaos matrix: every fault class must heal bit-exact with read
 availability >= 99%, the compactor-crash schedule must show a
 supervisor restart without escalation, and the kernel class a sticky
-failover), and the Chrome trace dump must be loadable with real
-events.
+failover), and the profiler traces must hold the program's
+``service.*`` and ``dispatch.*`` spans.
 
 Run after the bench-smoke steps:
 
@@ -23,6 +23,8 @@ instead of shipping a hollow artifact.
 
 from __future__ import annotations
 
+import glob
+import gzip
 import json
 import os
 import sys
@@ -180,21 +182,21 @@ def main() -> None:
         fail("faults['kernel_failover']: no sticky kernel->XLA failover "
              "was recorded")
 
-    # ---- Chrome trace dump ----------------------------------------------
-    trace_path = obs.get("trace_file") or ""
-    n_events = 0
-    if trace_path and os.path.exists(trace_path):
-        with open(trace_path) as f:
-            trace = json.load(f)
-        events = trace.get("traceEvents")
-        if not events:
-            fail(f"{trace_path} has no traceEvents")
-        for ev in events:
-            if "ph" not in ev or "name" not in ev:
-                fail(f"{trace_path} malformed event: {ev}")
-        n_events = len(events)
-    else:
-        fail(f"trace file {trace_path!r} missing")
+    # ---- profiler traces ------------------------------------------------
+    trace_dir = obs.get("trace_dir") or ""
+    paths = glob.glob(os.path.join(trace_dir, "**", "perfetto_trace.json.gz"),
+                      recursive=True) if trace_dir else []
+    if not paths:
+        fail(f"no perfetto_trace.json.gz under trace dir {trace_dir!r}")
+    names = set()
+    for path in paths:
+        with gzip.open(path, "rt") as f:
+            events = json.load(f).get("traceEvents") or []
+        names.update(str(ev.get("name", "")) for ev in events)
+    for prefix in ("service.", "dispatch."):
+        if not any(n.startswith(prefix) for n in names):
+            fail(f"no {prefix}* span in the traces under {trace_dir!r}")
+    n_names = len(names)
 
     print(
         f"check_obs_artifact: OK — {n_ops} latency rows over "
@@ -202,7 +204,8 @@ def main() -> None:
         f"{len(disp)} runs, {n_tenants} tenant rows over "
         f"{len(serving)} serve sweeps (SLO pass), {len(rec)} bit-exact "
         f"recoveries + leveled stall rows, {len(fault_rows)} fault classes "
-        f"healed (availability >= 99%), {n_events} trace events"
+        f"healed (availability >= 99%), {n_names} span names in "
+        f"{len(paths)} traces"
     )
 
 

@@ -65,7 +65,7 @@ from repro.index_service import (
 )
 from repro.kernels import ops as kernels_ops
 from repro.kernels.rmi_lookup import default_interpret
-from repro.obs import TRACER, write_chrome_trace
+from repro.obs import TRACER
 from repro.obs.export import op_latency_rows
 
 DELTA_CAPACITY = 4096
@@ -78,7 +78,9 @@ FUSED_BATCH = 4096
 # at exit (standalone LIX_*_ONLY runs merge into the same file, so the
 # CI bench-smoke steps accumulate one artifact)
 JSON_PATH = os.environ.get("LIX_BENCH_JSON", "BENCH_dynamic_index.json")
-TRACE_PATH = os.environ.get("LIX_TRACE_JSON", "BENCH_dynamic_index_trace.json")
+# profiler traces (program spans beside the device lanes), one run
+# directory per process, each with a perfetto_trace.json.gz
+TRACE_DIR = os.environ.get("LIX_TRACE_DIR", "BENCH_dynamic_index_trace")
 _JSON_ROWS: list = []
 # observability sections, merged into the artifact beside the rows:
 # per-service op-latency percentiles keyed by sweep label, the process
@@ -159,13 +161,10 @@ def write_json() -> None:
     data["observability"]["dispatch"][_RUN_LABEL] = (
         kernels_ops.dispatch_summary()
     )
-    data["observability"]["trace_file"] = TRACE_PATH
+    data["observability"]["trace_dir"] = TRACE_DIR
     with open(JSON_PATH, "w") as f:
         json.dump(data, f, indent=2)
     print(f"wrote {JSON_PATH} ({len(data['rows'])} rows)", flush=True)
-    if TRACER.enabled and len(TRACER):
-        write_chrome_trace(TRACE_PATH)
-        print(f"wrote {TRACE_PATH} ({len(TRACER)} spans)", flush=True)
 
 
 def dispatches(fn) -> int:
@@ -919,22 +918,26 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    TRACER.enable()  # spans land in the ring buffer; dumped at exit
-    if os.environ.get("LIX_SHARDED_ONLY", "0") == "1":
-        _RUN_LABEL = "sharded_sweep"
-        sharded_sweep()
-    elif os.environ.get("LIX_SCAN_ONLY", "0") == "1":
-        _RUN_LABEL = "scan_sweep"
-        scan_sweep()
-    elif os.environ.get("LIX_SERVE_ONLY", "0") == "1":
-        _RUN_LABEL = "serve_sweep"
-        serve_sweep()
-    elif os.environ.get("LIX_CHAOS_ONLY", "0") == "1":
-        _RUN_LABEL = "chaos_sweep"
-        chaos_sweep()
-    elif os.environ.get("LIX_FAULTS_ONLY", "0") == "1":
-        _RUN_LABEL = "fault_sweep"
-        fault_sweep()
-    else:
-        main()
+    import jax
+
+    TRACER.enable()  # program spans land in the profiler trace
+    with jax.profiler.trace(TRACE_DIR, create_perfetto_trace=True):
+        if os.environ.get("LIX_SHARDED_ONLY", "0") == "1":
+            _RUN_LABEL = "sharded_sweep"
+            sharded_sweep()
+        elif os.environ.get("LIX_SCAN_ONLY", "0") == "1":
+            _RUN_LABEL = "scan_sweep"
+            scan_sweep()
+        elif os.environ.get("LIX_SERVE_ONLY", "0") == "1":
+            _RUN_LABEL = "serve_sweep"
+            serve_sweep()
+        elif os.environ.get("LIX_CHAOS_ONLY", "0") == "1":
+            _RUN_LABEL = "chaos_sweep"
+            chaos_sweep()
+        elif os.environ.get("LIX_FAULTS_ONLY", "0") == "1":
+            _RUN_LABEL = "fault_sweep"
+            fault_sweep()
+        else:
+            main()
+    TRACER.disable()
     write_json()

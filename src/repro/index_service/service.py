@@ -474,17 +474,22 @@ class IndexService:
         surface."""
         t0 = time.perf_counter()
         with obs_trace.span("service.scan_batch", cat="service"):
-            snap, (ins, ivals, ins_rank, lp), ins_n = self._scan_plane_cached()
-            # static output-shape bound (host metadata sizing the output,
-            # not a rank fed to the device; see scan.scan_page_bound)
-            pages = scan_page_bound(
-                [snap.keys.raw], ins_n, lo, hi, page_size
-            )
-            fn = snap.scan_range_fn(self.config.strategy, page_size, pages)
-            bounds = jnp.asarray(
-                snap.keys.normalize(np.array([lo, hi], np.float64))
-            )
-            out = fn(bounds, ins, ivals, ins_rank, lp)
+            with obs_trace.span("service.prepare", cat="service"):
+                snap, (ins, ivals, ins_rank, lp), ins_n = (
+                    self._scan_plane_cached())
+                # static output-shape bound (host metadata sizing the
+                # output, not a rank fed to the device; see
+                # scan.scan_page_bound)
+                pages = scan_page_bound(
+                    [snap.keys.raw], ins_n, lo, hi, page_size
+                )
+                fn = snap.scan_range_fn(self.config.strategy, page_size,
+                                        pages)
+                bounds = jnp.asarray(
+                    snap.keys.normalize(np.array([lo, hi], np.float64))
+                )
+            with obs_trace.span("service.dispatch", cat="service"):
+                out = fn(bounds, ins, ivals, ins_rank, lp)
         dt = time.perf_counter() - t0
         self.stats["scan_batch"] += 1
         self.stats["scan_batch_s"] += dt
@@ -492,13 +497,20 @@ class IndexService:
         return out
 
     def _rank_exact(self, q: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-        snap, frozen, active, dk, dp = self._capture()
-        qn = jnp.asarray(snap.keys.normalize(q))
-        b, _ = snap.merged_lookup_fn(self.config.strategy)(qn, dk, dp)
-        # lixlint: host-sync(designed single read-back for f64 refinement)
-        base_rank, in_base = snap.refine_base_rank(q, np.asarray(b))
-        rank = base_rank + count_less(frozen, active, q)
-        live = live_mask(in_base, frozen, active, q)
+        """Device lower bounds, read back and refined exactly in f64 on
+        the host; four spans split the host time between the steps."""
+        with obs_trace.span("service.prepare", cat="service"):
+            snap, frozen, active, dk, dp = self._capture()
+            qn = jnp.asarray(snap.keys.normalize(q))
+        with obs_trace.span("service.dispatch", cat="service"):
+            b, _ = snap.merged_lookup_fn(self.config.strategy)(qn, dk, dp)
+        with obs_trace.span("service.readback", cat="service"):
+            # lixlint: host-sync(designed single read-back for f64 refinement)
+            b = np.asarray(b)
+        with obs_trace.span("service.refine", cat="service"):
+            base_rank, in_base = snap.refine_base_rank(q, b)
+            rank = base_rank + count_less(frozen, active, q)
+            live = live_mask(in_base, frozen, active, q)
         return rank, live
 
     # ---- writes ----------------------------------------------------------

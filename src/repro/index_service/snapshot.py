@@ -115,9 +115,14 @@ def _xla_base(q, tree, base_norm, *, strategy, n, num_leaves, max_window):
     jax.jit, static_argnames=("strategy", "n", "num_leaves", "max_window"))
 def _xla_merged(q, dkeys, dprefix, tree, base_norm, *, strategy, n,
                 num_leaves, max_window):
-    b = rmi_lookup(tree, base_norm, q, n=n, num_leaves=num_leaves,
-                   max_window=max_window, strategy=strategy)
-    return b, b + dprefix[search_lib.lower_bound_full(dkeys, q)]
+    # the scopes name the program's steps in its operations' metadata
+    with jax.named_scope("base_search"):
+        b = rmi_lookup(tree, base_norm, q, n=n, num_leaves=num_leaves,
+                       max_window=max_window, strategy=strategy)
+    with jax.named_scope("delta_search"):
+        d = search_lib.lower_bound_full(dkeys, q)
+    with jax.named_scope("prefix_gather"):
+        return b, b + dprefix[d]
 
 
 @functools.partial(
@@ -336,14 +341,12 @@ class IndexSnapshot:
 
             inner = merged
             kernel = strategy in KERNEL_STRATEGIES
-            snap_n = self.n
 
             def counted(q, dkeys, dprefix, _inner=inner):
                 # ONE device-program entry per call: count it and
                 # attribute wall time to (merged_lookup, strategy)
                 with kernels_ops.dispatch_span(
                     "merged_lookup", kernel=kernel, strategy=strategy,
-                    sig=(np.shape(q), np.shape(dkeys), snap_n, strategy),
                 ):
                     return _inner(q, dkeys, dprefix)
 
@@ -486,12 +489,10 @@ class IndexSnapshot:
                 inner = base
                 tag = alias.get(strategy, strategy)
                 kernel = strategy in KERNEL_STRATEGIES
-                snap_n = self.n
 
                 def base(q, _inner=inner):
                     with kernels_ops.dispatch_span(
                         "base_lookup", kernel=kernel, strategy=tag,
-                        sig=(np.shape(q), snap_n, tag),
                     ):
                         return _inner(q)
 

@@ -50,18 +50,17 @@ from repro.kernels.rmi_lookup import (
 # (a read path that silently regresses into per-shard or per-page
 # dispatch loops shows up as >1 per logical call) AND the cost model
 # its raw material: per-op wall time tagged kernel-vs-fallback and
-# strategy, plus retrace detection.
+# strategy, plus the executables compiled inside it.
 #
 # Counters are per-thread (`count_dispatches()` reads only the calling
 # thread's count, so the background compaction thread can never pollute
 # a test's window) with a thread-tagged global ledger alongside.
 #
-# Retrace proxy: jax recompiles a jitted program when the abstract
-# signature (shapes + static args) changes.  Each op hashes its
-# signature into a process-lifetime seen-set; a never-seen signature is
-# counted as a retrace.  The set deliberately survives
-# `reset_dispatch_stats()` — jax's compile caches do too, so clearing
-# it would report retraces that never happen.
+# Retraces are real compiles: JAX reports each executable it builds or
+# loads from the persistent cache on the compiling thread, and
+# `obs.metrics.count_compiles` charges it to the innermost dispatch
+# span open there.  A call that hits jax's in-memory caches compiles
+# nothing, before or after `reset_dispatch_stats()`.
 
 DISPATCH_COUNT = 0  # process-wide total, kept for back-compat reading
 
@@ -75,7 +74,6 @@ _TLS = _DispatchTls()
 _DISPATCH_LOCK = threading.Lock()
 _THREAD_COUNTS = {}      # thread name -> dispatches recorded on it
 _ATTRIBUTION = {}        # (op, path, strategy) -> [count, wall_s, retraces]
-_SEEN_SIGNATURES = set()  # (op, signature) — never cleared (see above)
 
 
 @functools.lru_cache(maxsize=None)
@@ -88,47 +86,43 @@ def _op_metrics(op: str, path: str):
     )
 
 
-def _record_dispatch(op, path, strategy, seconds, sig) -> None:
+def _record_dispatch(op, path, strategy, seconds, compiles) -> None:
     global DISPATCH_COUNT
     _TLS.count += 1
-    retrace = False
     key = (op, path, strategy or "")
     with _DISPATCH_LOCK:
         DISPATCH_COUNT += 1
         name = threading.current_thread().name
         _THREAD_COUNTS[name] = _THREAD_COUNTS.get(name, 0) + 1
-        if sig is not None:
-            sk = (op, sig)
-            if sk not in _SEEN_SIGNATURES:
-                _SEEN_SIGNATURES.add(sk)
-                retrace = True
         row = _ATTRIBUTION.get(key)
         if row is None:
             row = _ATTRIBUTION[key] = [0, 0.0, 0]
         row[0] += 1
         row[1] += seconds
-        row[2] += retrace
+        row[2] += compiles
     counter, hist, retraces = _op_metrics(op, path)
     counter.add(1)
     hist.observe(seconds)
-    if retrace:
-        retraces.add(1)
+    if compiles:
+        retraces.add(compiles)
 
 
 @contextlib.contextmanager
-def dispatch_span(op: str, *, kernel: bool, strategy=None, sig=()):
+def dispatch_span(op: str, *, kernel: bool, strategy=None):
     """Wrap ONE device-program entry: counts it (per-thread + global),
-    attributes its wall time to (op, kernel|fallback, strategy), flags
-    first-seen signatures as retraces, and emits a trace span."""
+    attributes its wall time to (op, kernel|fallback, strategy), counts
+    the executables compiled inside it as retraces, and emits a trace
+    span."""
     path = "kernel" if kernel else "fallback"
     t0 = time.perf_counter()
     with obs_trace.span(f"dispatch.{op}", cat="dispatch", path=path,
-                        strategy=strategy or ""):
+                        strategy=strategy or ""), \
+            obs_metrics.count_compiles() as compiles:
         try:
             yield
         finally:
             _record_dispatch(op, path, strategy,
-                             time.perf_counter() - t0, sig)
+                             time.perf_counter() - t0, compiles[0])
 
 
 @contextlib.contextmanager
@@ -164,16 +158,12 @@ def dispatch_summary() -> dict:
 
 def reset_dispatch_stats() -> None:
     """Zero the global ledger (per-thread deltas via `count_dispatches`
-    are unaffected; the retrace seen-set survives by design)."""
+    are unaffected)."""
     global DISPATCH_COUNT
     with _DISPATCH_LOCK:
         DISPATCH_COUNT = 0
         _THREAD_COUNTS.clear()
         _ATTRIBUTION.clear()
-
-
-def _shape(x):
-    return tuple(getattr(x, "shape", ()) or ())
 
 
 # ---------------------------------------------------------------------------
@@ -320,10 +310,7 @@ def rmi_lookup_op(index, sorted_keys_norm, q_norm, *, block_q=1024,
                   interpret=None):
     """Batched RMI lookup via the fused kernel.  `index` is an RMIndex.
     ``interpret=None`` auto-selects interpret mode off-TPU."""
-    with dispatch_span(
-        "rmi_lookup", kernel=True, strategy="pallas",
-        sig=(_shape(q_norm), index.n, index.num_leaves, block_q),
-    ):
+    with dispatch_span("rmi_lookup", kernel=True, strategy="pallas"):
         return rmi_lookup_pallas(
             jnp.asarray(q_norm),
             stage0_flat(index.stage0_params),
@@ -364,14 +351,12 @@ def rmi_merged_lookup_op(index, sorted_keys_norm, q_norm, delta_keys,
         jnp.asarray(delta_keys),
         jnp.asarray(delta_prefix),
     )
-    sig = (_shape(q_norm), _shape(delta_keys), index.n, block_q)
 
     def run_fallback():
         with dispatch_span(
             "rmi_merged_lookup", kernel=False,
             strategy=(strategy or "xla_fused") if not use_kernel
             else "xla_fused",
-            sig=sig + (False,),
         ):
             return ref.rmi_merged_lookup_reference(
                 *args, n=index.n, num_leaves=index.num_leaves,
@@ -384,7 +369,7 @@ def rmi_merged_lookup_op(index, sorted_keys_norm, q_norm, delta_keys,
     def run_kernel():
         with dispatch_span(
             "rmi_merged_lookup", kernel=True,
-            strategy=strategy or "pallas_fused", sig=sig + (True,),
+            strategy=strategy or "pallas_fused",
         ):
             return rmi_merged_lookup_pallas(
                 *args,
@@ -521,13 +506,11 @@ def rmi_sharded_merged_lookup_op(
         jnp.asarray(shard_n), jnp.asarray(shard_m),
         jnp.asarray(shard_ratio),
     )
-    sig = (_shape(q_stacked), _shape(sorted_keys), _shape(delta_keys),
-           block_q)
 
     def run_fallback():
         with dispatch_span(
             "rmi_sharded_merged_lookup", kernel=False,
-            strategy=strategy or "sharded_fused", sig=sig + (False,),
+            strategy=strategy or "sharded_fused",
         ):
             return _sharded_reference_jit(*args, max_window=max_window)
 
@@ -537,7 +520,7 @@ def rmi_sharded_merged_lookup_op(
     def run_kernel():
         with dispatch_span(
             "rmi_sharded_merged_lookup", kernel=True,
-            strategy=strategy or "sharded_fused", sig=sig + (True,),
+            strategy=strategy or "sharded_fused",
         ):
             return rmi_sharded_merged_lookup_pallas(
                 *args, hidden=tuple(hidden), max_window=max_window,
@@ -614,13 +597,9 @@ def rmi_scan_page_op(
         jnp.asarray(del_pos, jnp.int32),
         jnp.asarray(end_rank, jnp.int32).reshape(1),
     )
-    sig = (_shape(starts), _shape(base_keys), _shape(ins_keys), page_size)
 
     def run_fallback():
-        with dispatch_span(
-            "rmi_scan_page", kernel=False, strategy=strategy,
-            sig=sig + (False,),
-        ):
+        with dispatch_span("rmi_scan_page", kernel=False, strategy=strategy):
             keys, vals, live = _scan_page_reference_jit(
                 *args, page_size=page_size
             )
@@ -630,10 +609,7 @@ def rmi_scan_page_op(
         return run_fallback()
 
     def run_kernel():
-        with dispatch_span(
-            "rmi_scan_page", kernel=True, strategy=strategy,
-            sig=sig + (True,),
-        ):
+        with dispatch_span("rmi_scan_page", kernel=True, strategy=strategy):
             keys, vals, live = rmi_scan_page_pallas(
                 *args, page_size=page_size, interpret=interpret
             )
@@ -685,15 +661,9 @@ def rmi_scan_range_op(
         jnp.asarray(ins_vals, jnp.int32),
         jnp.asarray(ins_rank, jnp.int32),
     )
-    # pad-bucket resizes land here as fresh (shape, max_pages)
-    # signatures, i.e. retraces
-    sig = (_shape(base_keys), _shape(ins_keys), page_size, max_pages)
 
     def run_fallback():
-        with dispatch_span(
-            "rmi_scan_range", kernel=False, strategy=strategy,
-            sig=sig + (False,),
-        ):
+        with dispatch_span("rmi_scan_range", kernel=False, strategy=strategy):
             keys, vals, live = _scan_range_reference_jit(
                 *args, page_size=page_size, max_pages=max_pages
             )
@@ -703,10 +673,7 @@ def rmi_scan_range_op(
         return run_fallback()
 
     def run_kernel():
-        with dispatch_span(
-            "rmi_scan_range", kernel=True, strategy=strategy,
-            sig=sig + (True,),
-        ):
+        with dispatch_span("rmi_scan_range", kernel=True, strategy=strategy):
             keys, vals, live = rmi_scan_range_pallas(
                 *args, page_size=page_size, max_pages=max_pages,
                 interpret=interpret,
@@ -754,12 +721,10 @@ def rmi_sharded_scan_page_op(
         jnp.asarray(ins_vals, jnp.int32),
         jnp.asarray(ins_rank, jnp.int32),
     )
-    sig = (_shape(base_keys), _shape(ins_keys), page_size, max_pages)
 
     def run_fallback():
         with dispatch_span(
             "rmi_sharded_scan_page", kernel=False, strategy=strategy,
-            sig=sig + (False,),
         ):
             return _sharded_scan_jit(
                 *args, page_size=page_size, max_pages=max_pages,
@@ -772,7 +737,6 @@ def rmi_sharded_scan_page_op(
     def run_kernel():
         with dispatch_span(
             "rmi_sharded_scan_page", kernel=True, strategy=strategy,
-            sig=sig + (True,),
         ):
             return _sharded_scan_jit(
                 *args, page_size=page_size, max_pages=max_pages,
@@ -855,13 +819,11 @@ def rmi_sharded_routed_lookup_op(
         jnp.asarray(shard_ratio),
         jnp.asarray(base_off), jnp.asarray(merged_off),
     )
-    sig = (_shape(q_stacked), _shape(sorted_keys), _shape(delta_keys),
-           block_q)
 
     def run_fallback():
         with dispatch_span(
             "rmi_sharded_routed_lookup", kernel=False,
-            strategy=strategy or "sharded_fused", sig=sig + (False,),
+            strategy=strategy or "sharded_fused",
         ):
             return _sharded_routed_jit(
                 *args, hidden=tuple(hidden), max_window=max_window,
@@ -874,7 +836,7 @@ def rmi_sharded_routed_lookup_op(
     def run_kernel():
         with dispatch_span(
             "rmi_sharded_routed_lookup", kernel=True,
-            strategy=strategy or "sharded_fused", sig=sig + (True,),
+            strategy=strategy or "sharded_fused",
         ):
             return _sharded_routed_jit(
                 *args, hidden=tuple(hidden), max_window=max_window,

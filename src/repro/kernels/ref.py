@@ -198,10 +198,13 @@ def rmi_scan_range_reference(
         rmi_lookup_lib._xla(a) for a in
         (base_keys, base_vals, live_prefix, ins_keys, ins_vals, ins_rank)
     )
-    r = rmi_lookup_lib._merged_rank_from_prefix(
-        bounds, base_keys, live_prefix, ins_keys,
-        steps=steps, isteps=isteps,
-    )
+    # the scopes (here and in `_scan_rows_from_index`) name the
+    # program's steps in its operations' metadata
+    with jax.named_scope("endpoint_search"):
+        r = rmi_lookup_lib._merged_rank_from_prefix(
+            bounds, base_keys, live_prefix, ins_keys,
+            steps=steps, isteps=isteps,
+        )
     r0 = r[0]
     r1 = jnp.maximum(r[1], r0)
     t = r0 + jax.lax.broadcasted_iota(

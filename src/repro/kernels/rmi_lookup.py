@@ -377,24 +377,27 @@ def _scan_rows_from_index(
     inf = jnp.float32(jnp.inf)
     n, ni = base_keys.size, ins_keys.size
 
-    j = _array_lower_bound(ins_rank, t, ni, msteps)
-    a_i = t - j
-    # smallest idx with live_prefix[idx] >= a_i + 1; row position idx-1
-    p = _array_lower_bound(live_prefix, a_i + 1, n + 1, psteps) - 1
+    with jax.named_scope("live_prefix_search"):
+        j = _array_lower_bound(ins_rank, t, ni, msteps)
+        a_i = t - j
+        # smallest idx with live_prefix[idx] >= a_i + 1; row position idx-1
+        p = _array_lower_bound(live_prefix, a_i + 1, n + 1, psteps) - 1
 
-    a_key = jnp.where(
-        (p < 0) | (p >= n), inf, base_keys.read(jnp.clip(p, 0, n - 1))
-    )
-    a_val = base_vals.read(jnp.clip(p, 0, n - 1))
-    c_key = jnp.where(j >= ni, inf, ins_keys.read(jnp.clip(j, 0, ni - 1)))
-    c_val = ins_vals.read(jnp.clip(j, 0, ni - 1))
+    with jax.named_scope("row_gather"):
+        a_key = jnp.where(
+            (p < 0) | (p >= n), inf, base_keys.read(jnp.clip(p, 0, n - 1))
+        )
+        a_val = base_vals.read(jnp.clip(p, 0, n - 1))
+        c_key = jnp.where(j >= ni, inf,
+                          ins_keys.read(jnp.clip(j, 0, ni - 1)))
+        c_val = ins_vals.read(jnp.clip(j, 0, ni - 1))
 
-    from_ins = c_key < a_key
-    live = jnp.asarray(valid).astype(jnp.int32)
-    key = jnp.where(from_ins, c_key, a_key)
-    val = jnp.where(from_ins, c_val, a_val)
-    key = jnp.where(live == 1, key, inf)
-    val = jnp.where(live == 1, val, 0)
+        from_ins = c_key < a_key
+        live = jnp.asarray(valid).astype(jnp.int32)
+        key = jnp.where(from_ins, c_key, a_key)
+        val = jnp.where(from_ins, c_val, a_val)
+        key = jnp.where(live == 1, key, inf)
+        val = jnp.where(live == 1, val, 0)
     return key, val, live
 
 

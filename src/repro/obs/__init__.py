@@ -8,12 +8,12 @@ engine, benchmarks):
     gauges, and fixed log-bucket latency `Histogram`s cheap enough to
     record per op; percentile (p50/p90/p99) reads come straight off
     the bucket counts, no sample retention.
-  * ``obs.trace``   — a low-overhead span API (context manager over a
-    ring buffer) emitting Chrome trace-event JSON, so a mixed-op churn
-    run opens in ``chrome://tracing`` with service-op spans nesting
-    over router / kernel-dispatch / compactor-thread activity.
+  * ``obs.trace``   — a gated span API over `jax.profiler`
+    annotations: enabled, service-op spans nest over router /
+    kernel-dispatch / compactor-thread activity on the profiler's host
+    plane, on the device trace's clock.
   * ``obs.export``  — JSON snapshots and Prometheus text exposition
-    over any registry, plus the Chrome-trace writer.
+    over any registry.
 
 Service-level metrics live in per-service registries (so K shard
 services never alias each other's counters); cross-cutting dispatch
@@ -31,11 +31,9 @@ from repro.obs.metrics import (
 from repro.obs.trace import Tracer, TRACER, span, instant
 from repro.obs import lockstat
 from repro.obs.export import (
-    chrome_trace,
     op_latency_rows,
     prometheus_text,
     registry_json,
-    write_chrome_trace,
     write_json,
     write_prometheus,
 )
@@ -45,6 +43,6 @@ __all__ = [
     "default_registry",
     "Tracer", "TRACER", "span", "instant",
     "lockstat",
-    "chrome_trace", "op_latency_rows", "prometheus_text", "registry_json",
-    "write_chrome_trace", "write_json", "write_prometheus",
+    "op_latency_rows", "prometheus_text", "registry_json",
+    "write_json", "write_prometheus",
 ]

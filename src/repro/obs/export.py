@@ -1,20 +1,18 @@
-"""Exporters over the observability plane: JSON snapshots, Prometheus
-text exposition, Chrome trace files.
+"""Exporters over the observability plane: JSON snapshots and
+Prometheus text exposition.
 
 All exporters are pull-style and read-only — they take a point-in-time
-snapshot of a `MetricsRegistry` (or the process `TRACER`) and format
-it; nothing here mutates metric state, so exporting mid-run is safe
-from any thread.
+snapshot of a `MetricsRegistry` and format it; nothing here mutates
+metric state, so exporting mid-run is safe from any thread.
 """
 
 from __future__ import annotations
 
 import json
 import re
-from typing import Dict, Optional
+from typing import Dict
 
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
-from repro.obs.trace import TRACER, Tracer
 
 _NAME_RE = re.compile(r"[^a-zA-Z0-9_:]")
 
@@ -73,18 +71,6 @@ def prometheus_text(registry: MetricsRegistry) -> str:
 def write_prometheus(registry: MetricsRegistry, path: str) -> str:
     with open(path, "w") as f:
         f.write(prometheus_text(registry))
-    return path
-
-
-def chrome_trace(tracer: Optional[Tracer] = None) -> Dict[str, object]:
-    """Chrome trace-event JSON object for a tracer (default: the
-    process-wide `TRACER`)."""
-    return (tracer or TRACER).to_chrome()
-
-
-def write_chrome_trace(path: str, tracer: Optional[Tracer] = None) -> str:
-    with open(path, "w") as f:
-        json.dump(chrome_trace(tracer), f)
     return path
 
 

@@ -32,7 +32,9 @@ import math
 import threading
 import time
 from collections.abc import MutableMapping
-from typing import Dict, Iterable, Iterator, Optional, Tuple
+from typing import Dict, Iterable, Iterator, List, Optional, Tuple
+
+import jax
 
 # Fixed log-spaced histogram edges: 5 buckets per decade over 12
 # decades, 1e-7 s (100 ns) .. 1e5 s.  Shared by every latency histogram
@@ -265,6 +267,44 @@ _DEFAULT = MetricsRegistry("default")
 
 def default_registry() -> MetricsRegistry:
     return _DEFAULT
+
+
+# ---- compiles -------------------------------------------------------------
+# JAX reports every executable it builds, or loads from the persistent
+# cache, as one backend-compile duration event on the compiling thread.
+# One listener, registered at import, charges it to the innermost
+# `count_compiles` window open on that thread.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class _CompileWindow(threading.local):
+    def __init__(self):
+        self.box: Optional[List[int]] = None
+
+
+_COMPILE_WINDOW = _CompileWindow()
+
+
+@contextlib.contextmanager
+def count_compiles():
+    """Yields a one-item list that counts the executables compiled on
+    this thread while the window is open (an inner window takes the
+    compiles made inside it)."""
+    box, outer = [0], _COMPILE_WINDOW.box
+    _COMPILE_WINDOW.box = box
+    try:
+        yield box
+    finally:
+        _COMPILE_WINDOW.box = outer
+
+
+def _on_compile(event: str, duration: float, **_) -> None:
+    box = _COMPILE_WINDOW.box
+    if event == COMPILE_EVENT and box is not None:
+        box[0] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile)
 
 
 class StatsView(MutableMapping):

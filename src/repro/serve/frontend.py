@@ -178,6 +178,7 @@ class ServeRequest:
     )
     result: object = None
     error: Optional[BaseException] = None
+    round: Optional[int] = None    # the round that served it (its trace id)
 
     def wait(self, timeout: Optional[float] = None):
         if not self.event.wait(timeout):
@@ -237,6 +238,9 @@ class IndexFrontend:
         self._depth_gauge = self.metrics.gauge("frontend.queue_depth")
         self._deadline_ctr = self.metrics.counter("frontend.deadline_exceeded")
         self._probe_fail_ctr = self.metrics.counter("frontend.probe_failures")
+        self._queue_wait_ctr = self.metrics.counter("frontend.queue_wait_s")
+        self._read_lanes_ctr = self.metrics.counter("frontend.read_lanes")
+        self._padded_lanes_ctr = self.metrics.counter("frontend.padded_lanes")
         # degradation-ladder evidence.  Written by the single dispatcher
         # thread (pump); racy integer reads from client threads in
         # health() are tolerated — the ladder is advisory admission
@@ -404,7 +408,8 @@ class IndexFrontend:
         while True:
             with self._cond:
                 if not self._queue and not self._stopping:
-                    self._cond.wait(0.1)
+                    with obs_trace.span("frontend.wait", cat="serve"):
+                        self._cond.wait(0.1)
                 if not self._queue and self._stopping:
                     return
                 have = bool(self._queue)
@@ -434,34 +439,41 @@ class IndexFrontend:
         # deadline check at DISPATCH time: requests that aged out while
         # queued fail fast — a late answer is a wrong answer to an SLO.
         # The injected form of a scheduling stall backdates the whole
-        # batch past its deadline (deterministic, no sleeping).
+        # batch past its deadline (deterministic, no sleeping).  The
+        # same pass sums the round's queue wait (round start minus
+        # enqueue) into frontend.queue_wait_s, one add per round.
         ddl = self.config.request_deadline_s
         now = time.perf_counter()
         if ddl is not None and faults.should("frontend.queue.delay"):
             for r in batch:
                 r.enqueued_at = now - ddl - 1.0
         expired: List[ServeRequest] = []
-        if ddl is not None:
-            live: List[ServeRequest] = []
-            for r in batch:
-                age = now - r.enqueued_at
-                if age > ddl:
-                    r.error = DeadlineExceeded(
-                        f"{r.kind} request queued {age:.3f}s past its "
-                        f"{ddl}s deadline"
-                    )
-                    expired.append(r)
-                else:
-                    live.append(r)
-            if expired:
-                self._deadline_ctr.add(len(expired))
-                obs_trace.instant("frontend.deadline_exceeded",
-                                  cat="serve", n=len(expired))
-            batch = live
+        live: List[ServeRequest] = []
+        waited = 0.0
+        for r in batch:
+            age = now - r.enqueued_at
+            waited += age
+            if ddl is not None and age > ddl:
+                r.error = DeadlineExceeded(
+                    f"{r.kind} request queued {age:.3f}s past its "
+                    f"{ddl}s deadline"
+                )
+                expired.append(r)
+            else:
+                live.append(r)
+        self._queue_wait_ctr.add(waited)
+        if expired:
+            self._deadline_ctr.add(len(expired))
+            obs_trace.instant("frontend.deadline_exceeded",
+                              cat="serve", n=len(expired))
+        batch = live
         if batch:
             self._rounds_ctr.add(1)
+            n = self._rounds_ctr.value
+            for r in batch:
+                r.round = n
             self._coalesce_hist.observe(len(batch))
-            with obs_trace.span("frontend.round", cat="serve",
+            with obs_trace.span("frontend.round", cat="serve", round=n,
                                 requests=len(batch)), self._round_hist.time():
                 self._round(batch)
             self._observe_round(batch)
@@ -585,6 +597,8 @@ class IndexFrontend:
             padded = _pad_bucket(n)
             if padded > n:
                 q = np.concatenate([q, np.full(padded - n, q[-1])])
+        self._read_lanes_ctr.add(q.size)
+        self._padded_lanes_ctr.add(q.size - n)
         try:
             out = op(q)
         except BaseException as e:  # fault-wall: per-batch — coalesced reads fail together, dispatcher survives

@@ -203,3 +203,38 @@ def test_ryw_across_forced_freeze_single_thread():
         _, live = r.wait(1)
         assert live.all(), f"round {round_i} lost acked writes"
     assert svc.metrics.counter("delta.freezes").value >= 1
+
+
+# ---- round counters ---------------------------------------------------------
+
+def test_round_counters_are_exact(monkeypatch):
+    """Queue wait, lanes sent and padded lanes are added once a round,
+    exactly: the round starts at a fixed clock, each request was
+    enqueued a known time before it."""
+    from types import SimpleNamespace
+
+    from repro.serve import frontend as frontend_mod
+
+    fe = _frontend()
+    base = _lattice()
+    monkeypatch.setattr(frontend_mod, "time",
+                        SimpleNamespace(perf_counter=lambda: 1000.0))
+    waits = [0.25, 0.5, 1.0, 2.0]
+    reqs = [fe.submit("a", "get", base[:3]), fe.submit("b", "get", base[5:10]),
+            fe.submit("a", "contains", base[20:24]),
+            fe.submit("c", "scan", float(base[3]), float(base[9]), 64)]
+    for r, w in zip(reqs, waits):
+        r.enqueued_at = 1000.0 - w
+    fe.pump()
+    assert [r.round for r in reqs] == [1, 1, 1, 1]
+    ctr = {k: fe.metrics.counter(f"frontend.{k}").value
+           for k in ("queue_wait_s", "read_lanes", "padded_lanes")}
+    # 8 gets pad to one 64-lane batch, 4 contains to another; the scan
+    # sends no keyed lanes
+    assert ctr == {"queue_wait_s": sum(waits), "read_lanes": 128,
+                   "padded_lanes": 128 - 12}
+    fe.submit("a", "get", base[:2]).enqueued_at = 1000.0 - 4.0
+    fe.pump()
+    assert fe.metrics.counter("frontend.queue_wait_s").value == sum(waits) + 4
+    assert fe.metrics.counter("frontend.read_lanes").value == 192
+    assert fe.metrics.counter("frontend.padded_lanes").value == 192 - 14

@@ -13,14 +13,20 @@ Four contracts pinned here:
   3. Counters are monotone across structural events (rebalance retires
      shards and the router; compaction stalls and recovers) — the
      aggregate numbers in ``stats_summary`` never go backwards.
-  4. The trace ring buffer exports valid Chrome trace-event JSON with
-     the span nesting the plane promises (service -> dispatch,
-     compaction markers).
+  4. Program spans land on the profiler's host plane, one line per
+     thread, with the nesting the plane promises (frontend round ->
+     service op -> its steps -> dispatch), and only while the tracer is
+     enabled.
 """
 
+import glob
 import json
+import os
+import re
 import threading
+import time
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -34,9 +40,9 @@ from repro.obs import (
     MetricsRegistry,
     StatsView,
     Tracer,
-    chrome_trace,
 )
 from repro.obs import trace as obs_trace
+from repro.serve import IndexFrontend
 from repro.obs.export import op_latency_rows, prometheus_text
 from repro.obs.metrics import DEFAULT_LATENCY_EDGES
 
@@ -279,51 +285,167 @@ def test_plane_cache_hit_miss_counters():
 
 # ---- tracing --------------------------------------------------------------
 
-def test_disabled_tracer_records_nothing():
-    tr = Tracer()
-    with tr.span("x", cat="t"):
-        pass
-    tr.instant("y")
-    assert len(tr) == 0
+def _spans(log_dir, prefixes=("frontend.", "service.", "dispatch.")):
+    """[(name, start_ns, end_ns, line, stats)] of the program's spans in
+    the one profiler trace under ``log_dir``."""
+    import jax
+
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            out += [(e.name, e.start_ns, e.start_ns + e.duration_ns, i,
+                     dict(e.stats)) for e in line.events
+                    if e.name.startswith(prefixes)]
+    return out
 
 
-def test_trace_exports_valid_chrome_json():
-    obs_trace.TRACER.enable(capacity=65_536)
+def _traced(tmp_path, body, enable=True):
+    """Run ``body`` under a profiler trace (the program's spans on while
+    ``enable``) and return the spans that reached it."""
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    if enable:
+        obs_trace.TRACER.enable()
     try:
-        base = _lattice()
-        svc = ShardedIndexService(
-            base, ServiceConfig(delta_capacity=128, num_shards=2))
-        _drive_all_ops(svc, base)
-        svc.flush()
-        doc = json.loads(json.dumps(chrome_trace()))
+        body()
     finally:
         obs_trace.TRACER.disable()
-        obs_trace.TRACER.clear()
-    events = doc["traceEvents"]
-    assert events, "no spans captured"
-    names = set()
-    for ev in events:
-        assert "name" in ev and "ph" in ev
-        if ev["ph"] == "X":
-            assert ev["ts"] >= 0 and ev["dur"] >= 0
-            assert isinstance(ev["pid"], int) and isinstance(ev["tid"], int)
-            names.add(ev["name"])
-        elif ev["ph"] == "i":
-            names.add(ev["name"])
-    # the nesting the plane promises: service spans over dispatch
-    # spans, compaction markers from the background worker
-    assert any(n.startswith("service.") for n in names)
-    assert any(n.startswith("dispatch.") for n in names)
-    assert "service.compaction" in names or "delta.freeze" in names
+        jax.profiler.stop_trace()
+    return _spans(str(tmp_path))
 
 
-def test_trace_ring_buffer_bounds_memory():
-    tr = Tracer(capacity=16)
-    tr.enable()
-    for i in range(100):
-        with tr.span(f"s{i}"):
-            pass
-    assert len(tr) == 16  # oldest evicted, never grows
+def _inside(inner, outer):
+    return (inner[3] == outer[3] and outer[1] <= inner[1]
+            and inner[2] <= outer[2])
+
+
+def _pumped_gets(fe, base, n=3):
+    for c in range(n):
+        fe.submit(f"t{c}", "get", base[c * 5: c * 5 + 2])
+    fe.pump()
+
+
+def test_disabled_tracer_records_nothing(monkeypatch):
+    # disabled, a span is the shared null object and no annotation is
+    # ever built, not even for an instant
+    def no_annotation(*a, **k):
+        raise AssertionError("annotation opened while disabled")
+
+    monkeypatch.setattr(obs_trace, "TraceAnnotation", no_annotation)
+    tr = Tracer()
+    assert not tr.enabled
+    with tr.span("x", cat="t", n=1) as sp:
+        assert sp is tr.span("y")
+    tr.instant("z")
+    assert obs_trace.span("service.get") is tr.span("x")
+    obs_trace.instant("frontend.health")
+
+
+def test_program_spans_reach_the_profiler_trace(tmp_path):
+    base = _lattice()
+    fe = IndexFrontend(IndexService(base, ServiceConfig(delta_capacity=256)))
+    _pumped_gets(fe, base)  # compile outside the trace
+    spans = _traced(tmp_path, lambda: _pumped_gets(fe, base))
+    (rnd,) = [s for s in spans if s[0] == "frontend.round"]
+    assert rnd[4]["round"] == 2 and rnd[4]["requests"] == 3
+    (get,) = [s for s in spans if s[0] == "service.get"]
+    assert _inside(get, rnd)
+    steps = {s[0]: s for s in spans
+             if s[0] in ("service.prepare", "service.dispatch",
+                         "service.readback", "service.refine")}
+    assert len(steps) == 4
+    assert all(_inside(s, get) for s in steps.values())
+    # the steps follow each other in the order the work is done
+    order = sorted(steps.values(), key=lambda s: s[1])
+    assert [s[0] for s in order] == ["service.prepare", "service.dispatch",
+                                     "service.readback", "service.refine"]
+    (disp,) = [s for s in spans if s[0].startswith("dispatch.")]
+    assert _inside(disp, steps["service.dispatch"])
+
+
+def test_disabled_tracer_spans_stay_out_of_the_trace(tmp_path):
+    base = _lattice()
+    fe = IndexFrontend(IndexService(base, ServiceConfig(delta_capacity=256)))
+    _pumped_gets(fe, base)
+    assert _traced(tmp_path, lambda: _pumped_gets(fe, base),
+                   enable=False) == []
+
+
+def test_scan_batch_spans_split_prepare_and_dispatch(tmp_path):
+    base = _lattice()
+    svc = IndexService(base, ServiceConfig(delta_capacity=256),
+                       vals=np.arange(base.size))
+    lo, hi = float(base[3]), float(base[90])
+    svc.scan_batch(lo, hi, 64)
+    spans = _traced(tmp_path, lambda: svc.scan_batch(lo, hi, 64))
+    (scan,) = [s for s in spans if s[0] == "service.scan_batch"]
+    (prep,) = [s for s in spans if s[0] == "service.prepare"]
+    (disp,) = [s for s in spans if s[0] == "service.dispatch"]
+    (op,) = [s for s in spans if s[0] == "dispatch.rmi_scan_range"]
+    assert _inside(prep, scan) and _inside(disp, scan)
+    assert prep[2] <= disp[1] and _inside(op, disp)
+
+
+def test_idle_dispatcher_waits_in_a_span_and_instants_are_points(tmp_path):
+    fe = IndexFrontend(IndexService(_lattice(),
+                                    ServiceConfig(delta_capacity=256)))
+
+    def idle():
+        fe.start()
+        time.sleep(0.3)
+        obs_trace.instant("frontend.health", state="HEALTHY")
+        fe.stop()
+
+    spans = _traced(tmp_path, idle)
+    waits = [s for s in spans if s[0] == "frontend.wait"]
+    assert waits and all(s[2] > s[1] for s in waits)
+    (health,) = [s for s in spans if s[0] == "frontend.health"]
+    # an instant opens and closes at once: a point beside the waits
+    assert health[2] - health[1] < 1e6 and health[4]["state"] == "HEALTHY"
+    # the dispatcher's waits share one line, not the caller's
+    assert {s[3] for s in waits} != {health[3]}
+
+
+def _lookup_program(svc, base):
+    from repro.index_service import snapshot
+
+    snap, _, _, dk, dp = svc._capture()
+    idx = snap.index
+    return snapshot._xla_merged.lower(
+        jnp.asarray(snap.keys.normalize(base[:64])), dk, dp,
+        idx.as_pytree(), snap._device_base()[0], strategy="binary",
+        n=idx.n, num_leaves=idx.num_leaves, max_window=idx.max_window)
+
+
+def _scan_program(svc, base):
+    from repro.kernels import ops
+
+    snap, (ins, ivals, ins_rank, lp), _ = svc._scan_plane_cached()
+    bounds = jnp.asarray(snap.keys.normalize(base[[3, 90]]), jnp.float32)
+    return ops._scan_range_reference_jit.lower(
+        bounds, *snap._device_base(), lp, ins, ivals, ins_rank,
+        page_size=64, max_pages=2)
+
+
+@pytest.mark.parametrize("program,scopes", [
+    (_lookup_program, ("base_search", "delta_search", "prefix_gather")),
+    (_scan_program, ("endpoint_search", "live_prefix_search", "row_gather")),
+])
+def test_device_programs_name_their_steps(program, scopes):
+    """Each step of the served device programs carries a named scope in
+    its operations' metadata, the name a device trace shows."""
+    base = _lattice()
+    svc = IndexService(base, ServiceConfig(delta_capacity=256),
+                       vals=np.arange(base.size))
+    hlo = program(svc, base).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for scope in scopes:
+        assert any(f"/{scope}/" in n for n in names), scope
 
 
 # ---- exporters ------------------------------------------------------------
