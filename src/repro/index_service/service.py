@@ -457,13 +457,15 @@ class IndexService:
         return snap, slab, ins_n
 
     def scan_batch(self, lo: float, hi: float, page_size: int = 256):
-        """Device fast path for scans: ONE dispatch — endpoint ranking,
-        page starts, and every page gather fused into a single device
-        program (`snapshot.scan_range_fn`: one pallas_call under the
-        kernel strategies, the bit-identical XLA program otherwise).
-        The merged ranks ``(r0, r1)`` of [lo, hi) never touch the host;
-        the only host work is a cache-hit on the scan plane and a
-        conservative page-count bound for the static output shape.
+        """Device fast path for scans: ONE device program — endpoint
+        ranking, page starts, every page gather and the live mask's
+        bool cast fused into a single program (`snapshot.scan_range_fn`:
+        one pallas_call under the kernel strategies, the bit-identical
+        XLA program otherwise), whose call also uploads the normalized
+        bounds.  The merged ranks ``(r0, r1)`` of [lo, hi) never touch
+        the host; the only host work is a cache-hit on the scan plane,
+        a conservative page-count bound for the static output shape and
+        the bounds' normalization.
 
         Returns ``(keys (G, page_size) f32, vals i32, live_mask)`` in
         the snapshot's *normalized float32 frame* with int32 values;
@@ -485,9 +487,8 @@ class IndexService:
                 )
                 fn = snap.scan_range_fn(self.config.strategy, page_size,
                                         pages)
-                bounds = jnp.asarray(
-                    snap.keys.normalize(np.array([lo, hi], np.float64))
-                )
+                # a host array: the scan program's call uploads it
+                bounds = snap.keys.normalize(np.array([lo, hi], np.float64))
             with obs_trace.span("service.dispatch", cat="service"):
                 out = fn(bounds, ins, ivals, ins_rank, lp)
         dt = time.perf_counter() - t0

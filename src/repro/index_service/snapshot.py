@@ -411,10 +411,11 @@ class IndexSnapshot:
         """jit fn (bounds, ins_keys, ins_vals, ins_rank, live_prefix)
         -> (keys (max_pages, page_size) f32, vals i32, live_mask bool)
         — the FUSED scan read path: the merged ranks of ``bounds =
-        [lo, hi)``, every page start, and every row gather all happen
-        inside one device program (`kernels.ops.rmi_scan_range_op`:
-        one pallas_call under the kernel strategies, the bit-identical
-        XLA program otherwise).  Nothing ranks on the host;
+        [lo, hi)``, every page start, every row gather and the live
+        mask's bool cast all happen inside one device program
+        (`kernels.ops.rmi_scan_range_op`: one pallas_call under the
+        kernel strategies, the bit-identical XLA program otherwise).
+        Nothing ranks on the host;
         ``max_pages`` is only the static output-shape bound (pages past
         the range come back masked).  Delta inputs come from
         `scan.device_scan_slab`, cached by the service per (snapshot,

@@ -587,51 +587,57 @@ def rmi_scan_page_op(
     int32 — the host `index_service.scan` path is the exact float64
     surface; this op is its device data plane.  ``live_mask`` is True
     for rows below ``end_rank`` (partial last page, empty ranges).
+    One device program per call, argument casts and the bool mask
+    included (`_scan_page_jit`); inputs may be host or device arrays.
     """
-    args = (
-        jnp.asarray(starts, jnp.int32),
-        jnp.asarray(base_keys, jnp.float32),
-        jnp.asarray(base_vals, jnp.int32),
-        jnp.asarray(ins_keys, jnp.float32),
-        jnp.asarray(ins_vals, jnp.int32),
-        jnp.asarray(del_pos, jnp.int32),
-        jnp.asarray(end_rank, jnp.int32).reshape(1),
-    )
+    args = (starts, base_keys, base_vals, ins_keys, ins_vals, del_pos,
+            end_rank)
 
     def run_fallback():
         with dispatch_span("rmi_scan_page", kernel=False, strategy=strategy):
-            keys, vals, live = _scan_page_reference_jit(
-                *args, page_size=page_size
-            )
-            return keys, vals, live.astype(bool)
+            return _scan_page_jit(*args, page_size=page_size,
+                                  use_kernel=False, interpret=interpret)
 
     if not use_kernel:
         return run_fallback()
 
     def run_kernel():
         with dispatch_span("rmi_scan_page", kernel=True, strategy=strategy):
-            keys, vals, live = rmi_scan_page_pallas(
-                *args, page_size=page_size, interpret=interpret
-            )
-            return keys, vals, live.astype(bool)
+            return _scan_page_jit(*args, page_size=page_size,
+                                  use_kernel=True, interpret=interpret)
 
     return run_with_failover(
         "rmi_scan_page", strategy, run_kernel, run_fallback,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("page_size",))
-def _scan_page_reference_jit(
+@functools.partial(
+    jax.jit, static_argnames=("page_size", "use_kernel", "interpret")
+)
+def _scan_page_jit(
     starts, base_keys, base_vals, ins_keys, ins_vals, del_pos, end_rank,
-    *, page_size,
+    *, page_size, use_kernel, interpret,
 ):
-    if starts.shape[0] == 0:
-        empty = jnp.zeros((0, page_size), jnp.int32)
-        return empty.astype(jnp.float32), empty, empty
-    return ref.rmi_scan_page_reference(
-        starts, base_keys, base_vals, ins_keys, ins_vals, del_pos,
-        end_rank, page_size=page_size,
+    # the dtype contract lives in the trace: a cast to the dtype an
+    # argument already has is no operation
+    args = (
+        starts.astype(jnp.int32), base_keys.astype(jnp.float32),
+        base_vals.astype(jnp.int32), ins_keys.astype(jnp.float32),
+        ins_vals.astype(jnp.int32), del_pos.astype(jnp.int32),
+        jnp.reshape(end_rank, (1,)).astype(jnp.int32),
     )
+    if use_kernel:
+        keys, vals, live = rmi_scan_page_pallas(
+            *args, page_size=page_size, interpret=interpret
+        )
+    elif starts.shape[0] == 0:
+        keys = jnp.zeros((0, page_size), jnp.float32)
+        vals = live = jnp.zeros((0, page_size), jnp.int32)
+    else:
+        keys, vals, live = ref.rmi_scan_page_reference(
+            *args, page_size=page_size
+        )
+    return keys, vals, live.astype(bool)
 
 
 def rmi_scan_range_op(
@@ -640,8 +646,9 @@ def rmi_scan_range_op(
     interpret=None, strategy=None,
 ):
     """Fused endpoint-ranking + paged merged-scan gather: ONE device
-    dispatch computes the merged ranks of ``bounds = [lo, hi)`` and
-    streams every page of rows in between -> (keys, vals, live_mask).
+    program computes the merged ranks of ``bounds = [lo, hi)``, streams
+    every page of rows in between and casts the live mask ->
+    (keys f32, vals i32, live_mask bool).
 
     The successor to `rmi_scan_page_op` for the service scan path: no
     host rank feeds the program — ranks, page starts, and rows all
@@ -651,49 +658,61 @@ def rmi_scan_range_op(
     is a conservative *shape* bound (base window + staged inserts);
     pages past the true range come back fully masked.  Kernel and XLA
     fallback share the same body — bit-identical for every input.
+    Inputs may be host or device arrays: the dtype casts run inside
+    the program (`_scan_range_jit`), so a host ``bounds`` array is
+    uploaded by the call itself.
     """
-    args = (
-        jnp.asarray(bounds, jnp.float32),
-        jnp.asarray(base_keys, jnp.float32),
-        jnp.asarray(base_vals, jnp.int32),
-        jnp.asarray(live_prefix, jnp.int32),
-        jnp.asarray(ins_keys, jnp.float32),
-        jnp.asarray(ins_vals, jnp.int32),
-        jnp.asarray(ins_rank, jnp.int32),
-    )
+    args = (bounds, base_keys, base_vals, live_prefix, ins_keys, ins_vals,
+            ins_rank)
 
     def run_fallback():
         with dispatch_span("rmi_scan_range", kernel=False, strategy=strategy):
-            keys, vals, live = _scan_range_reference_jit(
-                *args, page_size=page_size, max_pages=max_pages
+            return _scan_range_jit(
+                *args, page_size=page_size, max_pages=max_pages,
+                use_kernel=False, interpret=interpret,
             )
-            return keys, vals, live.astype(bool)
 
     if not use_kernel:
         return run_fallback()
 
     def run_kernel():
         with dispatch_span("rmi_scan_range", kernel=True, strategy=strategy):
-            keys, vals, live = rmi_scan_range_pallas(
+            return _scan_range_jit(
                 *args, page_size=page_size, max_pages=max_pages,
-                interpret=interpret,
+                use_kernel=True, interpret=interpret,
             )
-            return keys, vals, live.astype(bool)
 
     return run_with_failover(
         "rmi_scan_range", strategy, run_kernel, run_fallback,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("page_size", "max_pages"))
-def _scan_range_reference_jit(
+@functools.partial(
+    jax.jit,
+    static_argnames=("page_size", "max_pages", "use_kernel", "interpret"),
+)
+def _scan_range_jit(
     bounds, base_keys, base_vals, live_prefix, ins_keys, ins_vals,
-    ins_rank, *, page_size, max_pages,
+    ins_rank, *, page_size, max_pages, use_kernel, interpret,
 ):
-    return ref.rmi_scan_range_reference(
-        bounds, base_keys, base_vals, live_prefix, ins_keys, ins_vals,
-        ins_rank, page_size=page_size, max_pages=max_pages,
+    # the dtype contract lives in the trace: a cast to the dtype an
+    # argument already has is no operation
+    args = (
+        bounds.astype(jnp.float32), base_keys.astype(jnp.float32),
+        base_vals.astype(jnp.int32), live_prefix.astype(jnp.int32),
+        ins_keys.astype(jnp.float32), ins_vals.astype(jnp.int32),
+        ins_rank.astype(jnp.int32),
     )
+    if use_kernel:
+        keys, vals, live = rmi_scan_range_pallas(
+            *args, page_size=page_size, max_pages=max_pages,
+            interpret=interpret,
+        )
+    else:
+        keys, vals, live = ref.rmi_scan_range_reference(
+            *args, page_size=page_size, max_pages=max_pages,
+        )
+    return keys, vals, live.astype(bool)
 
 
 def rmi_sharded_scan_page_op(

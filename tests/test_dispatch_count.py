@@ -11,6 +11,14 @@ Pinned: `IndexService.scan_batch`, `ShardedIndexService.scan_batch`,
 `ShardedIndexService.lookup_batch` / `get` / `contains` — exactly one
 dispatch per call, kernel strategies and XLA fallbacks alike, cache
 cold or warm.
+
+The dispatch counter sees op boundaries only: an eager jnp op run
+after the jitted program inside the same op (a dtype cast of its
+output, say) is a second device program it cannot see.  Programs are
+pinned by what JAX compiles instead: a cold call at a fresh signature
+builds one executable per program it launches, so
+`test_cold_scan_batch_compiles_one_program` holds `scan_batch` to
+exactly one.
 """
 
 import threading
@@ -54,6 +62,49 @@ def test_scan_batch_single_dispatch(strategy):
     with ops.count_dispatches() as n:
         svc.scan_batch(lo, hi, 128)
         assert n() == 1
+
+
+@pytest.mark.parametrize("strategy", ["binary", "pallas_fused"])
+def test_cold_scan_batch_compiles_one_program(strategy):
+    """A cold `scan_batch` compiles exactly one executable — the fused
+    scan with its argument casts and the live mask's bool cast inside
+    — on the XLA twin and the kernel path alike: no eager op runs
+    before or after the program."""
+    import jax
+
+    from repro.obs import metrics as obs_metrics
+
+    me = threading.get_ident()
+    seen = []
+
+    def listener(event, duration, **_):
+        if event == obs_metrics.COMPILE_EVENT and threading.get_ident() == me:
+            seen.append(event)
+
+    def retraces():
+        return sum(r["retraces"] for r in ops.dispatch_summary()["rows"]
+                   if r["op"] == "rmi_scan_range"
+                   and r["strategy"] == strategy)
+
+    base = _lattice()
+    svc = IndexService(
+        base, ServiceConfig(delta_capacity=512, strategy=strategy),
+        vals=np.arange(base.size, dtype=np.int64),
+    )
+    svc.insert(np.arange(3, 300, 7, dtype=np.float64) * 1024.0 + 512.0)
+    lo, hi = float(base[10]), float(base[-10])
+    svc.scan_batch(lo, hi, 128)  # builds and uploads the scan plane
+    before = retraces()
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        # a page size no other test (nor the other strategy) uses
+        out = svc.scan_batch(lo, hi, {"binary": 112, "pallas_fused": 120}[
+            strategy])
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert len(seen) == 1
+    assert retraces() - before == 1
+    assert out[2].dtype == bool
 
 
 @pytest.mark.parametrize("strategy", ["binary", "pallas_fused"])

@@ -427,9 +427,9 @@ def _scan_program(svc, base):
 
     snap, (ins, ivals, ins_rank, lp), _ = svc._scan_plane_cached()
     bounds = jnp.asarray(snap.keys.normalize(base[[3, 90]]), jnp.float32)
-    return ops._scan_range_reference_jit.lower(
+    return ops._scan_range_jit.lower(
         bounds, *snap._device_base(), lp, ins, ivals, ins_rank,
-        page_size=64, max_pages=2)
+        page_size=64, max_pages=2, use_kernel=False, interpret=None)
 
 
 @pytest.mark.parametrize("program,scopes", [

@@ -17,6 +17,8 @@ The load-bearing guarantees:
     ranges yield no pages.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -271,6 +273,68 @@ def test_scan_batch_device_matches_host_pages():
         snap = svc._mgr.current()
         np.testing.assert_array_equal(got_k, snap.keys.normalize(host_k))
         np.testing.assert_array_equal(got_v, host_v.astype(np.int32))
+
+
+def _fused_scan_inputs(op):
+    """(op, args, static arguments, reference) for one fused scan op
+    over a churned lattice: `rmi_scan_range_op` over the service's scan plane, or
+    `rmi_scan_page_op` over its rank-addressed plan."""
+    from repro.index_service.scan import device_scan_plan, pin_view
+    from repro.kernels import ref
+
+    base = np.arange(2, 3002, dtype=np.float64) * 1024.0
+    svc = IndexService(
+        base, ServiceConfig(delta_capacity=512),
+        vals=np.arange(base.size, dtype=np.int64) * 3,
+    )
+    svc.insert(np.arange(3, 700, 7, dtype=np.float64) * 1024.0 + 512.0,
+               np.arange(100, dtype=np.int64) + 10_000)
+    svc.delete(base[::13])
+    lo, hi = base[100], base[400]
+    snap = svc._mgr.current()
+    base_norm, bvals = (np.asarray(a) for a in snap._device_base())
+    if op == "range":
+        _, (ins, ivals, ins_rank, lp), _ = svc._scan_plane_cached()
+        args = (snap.keys.normalize(np.array([lo, hi])), base_norm, bvals,
+                np.asarray(lp), np.asarray(ins), np.asarray(ivals),
+                np.asarray(ins_rank))
+        static = dict(page_size=64, max_pages=6)  # the last page: masked
+        return ops.rmi_scan_range_op, args, static, (
+            ref.rmi_scan_range_reference)
+    view = pin_view(snap, svc._frozen, svc._active)
+    r0, r1 = (int(r) for r in view.rank(np.array([lo, hi])))
+    ins, ivals, dpos = device_scan_plan(view, snap.keys.normalize)
+    starts = np.arange(r0, r1 + 64, 64, dtype=np.int32)
+    args = (starts, base_norm, bvals, ins, ivals, dpos,
+            np.array([r1], np.int32))
+    return ops.rmi_scan_page_op, args, dict(page_size=64), (
+        ref.rmi_scan_page_reference)
+
+
+@pytest.mark.parametrize("op", ["range", "page"])
+@pytest.mark.parametrize("use_kernel", [False, True])
+@pytest.mark.parametrize("where", ["numpy", "device"])
+def test_fused_scan_ops_return_reference_rows_and_bool_mask(
+    op, use_kernel, where
+):
+    """The fused scan ops return the reference's rows as they leave
+    it, with the live mask cast to bool inside the one program, whether
+    the arguments arrive as NumPy or as device arrays — kernel
+    (interpret mode off-TPU) and XLA twin alike."""
+    import jax
+    import jax.numpy as jnp
+
+    fn, args, static, reference = _fused_scan_inputs(op)
+    want = jax.jit(functools.partial(reference, **static))(
+        *(jnp.asarray(a) for a in args))
+    if where == "device":
+        args = tuple(jnp.asarray(a) for a in args)
+    got = fn(*args, use_kernel=use_kernel, **static)
+    assert got[2].dtype == bool
+    assert np.asarray(want[2]).any() and not np.asarray(want[2]).all()
+    for g, w in zip(got, (want[0], want[1], want[2].astype(bool))):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def _sharded_lattice(k, n=9_000, strategy="binary"):

@@ -123,6 +123,26 @@ def test_scan_page_compiles(one_chip):
         rmi_lookup.rmi_scan_page_pallas, args, dict(page_size=PAGE))
 
 
+@pytest.mark.parametrize("op", ["range", "page"])
+def test_fused_scan_op_program_compiles(one_chip, op):
+    """The fused scan ops' one program — the kernel with the argument
+    casts and the live mask's bool cast around it — compiles whole."""
+    from repro.kernels import ops
+
+    if op == "range":
+        fn, static = ops._scan_range_jit, dict(max_pages=PAGES)
+        args = _shapes(one_chip, ((2,), F32), ((N,), F32), ((N,), I32),
+                       ((N + 1,), I32), ((D,), F32), ((D,), I32),
+                       ((D,), I32))
+    else:
+        fn, static = ops._scan_page_jit, {}
+        args = _shapes(one_chip, ((PAGES,), I32), ((N,), F32),
+                       ((N,), I32), ((D,), F32), ((D,), I32), ((D,), I32),
+                       ((1,), I32))
+    assert _compiled_kernel(
+        fn, args, dict(page_size=PAGE, use_kernel=True, **static))
+
+
 def test_sharded_scan_page_compiles(one_chip):
     ns = N // S
     args = _shapes(one_chip, ((S, ns), F32), ((S, ns), I32),
