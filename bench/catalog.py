@@ -1,12 +1,16 @@
 """Finds every piece of a cell by its name in `BENCHMARK.json`:
 
-* ``bench/configs/<config>.json``   one deployment;
-* ``bench/traffic/<mix>.json``      one traffic mix;
-* ``bench/metrics/<metric>.py``     one metric's reader, ``read(rec)``;
-* ``bench/peaks.json``              the chips' peaks, by ``device_kind``.
+* ``bench/configs/<config>.json``     one deployment;
+* ``bench/generators/<name>.py``      the key generator a configuration
+  names (``"generator"``), ``generate(n, seed, shape_seed)``;
+* ``bench/traffic/<mix>.json``        one traffic mix;
+* ``bench/ops/<kind>.py``             one request kind a mix's ``ops``
+  names (see `bench.traffic` for what a kind module gives);
+* ``bench/metrics/<metric>.py``       one metric's reader, ``read(rec)``;
+* ``bench/peaks.json``                the chips' peaks, by ``device_kind``.
 
-A later cell, mix, configuration or metric is added as files and
-entries; nothing here names one.
+A later cell, mix, configuration, request kind, key generator or metric
+is added as files and entries; nothing here names one.
 """
 
 from __future__ import annotations
@@ -28,6 +32,7 @@ class Cell:
     mix: dict
     end_to_end: List[dict]   # the metrics a --trace 0 run reports
     per_layer: List[dict]    # the metrics a --trace 1 run reports
+    root: pathlib.Path = ROOT  # the checkout its files came from
 
 
 def _load_json(path: pathlib.Path) -> dict:
@@ -61,19 +66,36 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     reported = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"] if _applies(m, name, reported)]
     return Cell(name=name, chips=int(w["chips"]), config=config, mix=mix,
-                end_to_end=e2e, per_layer=per_layer)
+                end_to_end=e2e, per_layer=per_layer, root=root)
+
+
+def _load_module(folder: str, name: str, root: pathlib.Path):
+    """The module ``bench/<folder>/<name>.py`` of the checkout ``root``;
+    LookupError where there is no such file."""
+    path = root / "bench" / folder / f"{name}.py"
+    if not path.is_file():
+        raise LookupError(f"no {folder} module {name!r} at {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{folder}_" + name.replace(".", "_").replace("-", "_"), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 def load_reader(metric: str, root: pathlib.Path = ROOT) -> Callable:
     """``read(rec) -> float | None`` from ``bench/metrics/<metric>.py``."""
-    path = root / "bench" / "metrics" / f"{metric}.py"
-    if not path.is_file():
-        raise LookupError(f"no reader for metric {metric!r} at {path}")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return _load_module("metrics", metric, root).read
+
+
+def load_generator(name: str, root: pathlib.Path = ROOT) -> Callable:
+    """``generate(n, seed, shape_seed) -> sorted unique keys`` from
+    ``bench/generators/<name>.py``; the keys keep the dtype it returns."""
+    return _load_module("generators", name, root).generate
+
+
+def load_kinds(names, root: pathlib.Path = ROOT) -> Dict[str, object]:
+    """``{kind: module}`` from ``bench/ops/<kind>.py`` for each name."""
+    return {k: _load_module("ops", k, root) for k in names}
 
 
 def load_peaks(device_kind: str,

@@ -6,7 +6,10 @@ and request stream.  It has to come out as not correct.
     python3 bench/control.py --workload <cell> --seconds <s> --seeds 1,2,3
 
 Prints one JSON line per seed with each number the benchmark compares
-(its limit is 0).  The benchmark's own runs never run it.
+(its limit is 0), from each request kind's ``control(oracle, plan,
+idx)`` (`bench/ops/`; a kind without one has no control here) over the
+keys stored when the window opens.  The benchmark's own runs never run
+it.
 """
 
 import argparse
@@ -27,30 +30,28 @@ def main(argv=None) -> int:
     sys.path[:0] = [str(ROOT)]
     import numpy as np
 
-    from bench import catalog, datagen, reference
-    from bench.traffic import KINDS, make_plan
+    from bench import catalog, reference, traffic
 
     cell = catalog.load_cell(args.workload, ROOT)
     cfg = cell.config
+    ops = traffic.load_ops(cell.mix, ROOT)
     for seed in (int(s) for s in args.seeds.split(",")):
-        keys = datagen.GENERATORS[cfg["generator"]](
+        final = catalog.load_generator(cfg["generator"], ROOT)(
             int(cfg["keys"]), seed, int(cfg["shape_seed"]))
-        oracle = reference.Oracle(keys, np.arange(keys.size, dtype=np.int64))
-        plan = make_plan(cell.mix, keys, seed, args.seconds)
-        row = {"workload": cell.name, "seed": seed, "keys": int(keys.size),
-               "requests": plan.size}
-        gets = plan.kind == KINDS.index("get")
-        if gets.any():
-            q = plan.lo[gets]
-            row["get_wrong"] = reference.gets_wrong(
-                oracle, q, *oracle.get_control(q))
-        scans = np.flatnonzero(plan.kind == KINDS.index("scan"))
-        if scans.size:
-            row["scan_wrong"] = int(sum(
-                reference.scan_wrong(oracle, plan.lo[i], plan.hi[i],
-                                     *oracle.scan_control(plan.lo[i],
-                                                          plan.hi[i]))
-                for i in scans))
+        adds = sum(c for k, c in traffic.kind_counts(
+            cell.mix, args.seconds).items() if ops[k].ADDS_KEYS)
+        held = traffic.hold_back(cell.mix, final, adds, seed)
+        space = traffic.KeySpace(final, held, int(final.size - held.size))
+        stored = space.base()
+        oracle = reference.Oracle(final[stored], stored)
+        plan = traffic.make_plan(cell.mix, space, seed, args.seconds,
+                                 ops=ops)
+        row = {"workload": cell.name, "seed": seed,
+               "keys": int(stored.size), "requests": plan.size}
+        for c, kind in enumerate(plan.kinds):
+            idx = np.flatnonzero(plan.kind == c)
+            if idx.size and hasattr(ops[kind], "control"):
+                row.update(ops[kind].control(oracle, plan, idx))
         print(json.dumps(row), flush=True)
     return 0
 

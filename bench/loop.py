@@ -5,7 +5,9 @@ answer is in the client's hands.
 Three threads share the process (the chip belongs to one process):
 this module's generator (the caller's thread), the frontend's own
 dispatcher, and a collector that waits for answers in send order and
-brings scan rows to the host.
+brings scan rows to the host.  What each request kind sends and keeps
+comes from its module (``ops``, `bench.traffic`), looked up once per
+kind before the window.
 """
 
 from __future__ import annotations
@@ -14,24 +16,24 @@ import dataclasses
 import queue
 import threading
 import time
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
 
-from bench.traffic import KINDS, Plan
+from bench.traffic import Plan
 
 TENANT = "client"
 GRACE_S = 60.0   # how long past the close an answer is still waited for
 
 
 class TimedService:
-    """Sits between `IndexFrontend` and the service: times each ``get``
-    and ``scan_batch`` call on the host clock and wraps it in a profiler
-    annotation (``bench.service.<op>``).  It adds no sync: a call's
-    time is what the service spends before it returns."""
+    """Sits between `IndexFrontend` and the service: times each ``get``,
+    ``scan_batch`` and ``insert`` call on the host clock and wraps it in
+    a profiler annotation (``bench.service.<op>``).  It adds no sync: a
+    call's time is what the service spends before it returns."""
 
-    OPS = ("get", "scan_batch")
+    OPS = ("get", "scan_batch", "insert")
 
     def __init__(self, service):
         self._service = service
@@ -57,6 +59,9 @@ class TimedService:
     def scan_batch(self, lo, hi, page_size=256):
         return self._timed("scan_batch", self._service.scan_batch, lo, hi,
                            page_size)
+
+    def insert(self, keys, vals=None):
+        return self._timed("insert", self._service.insert, keys, vals)
 
     def totals(self) -> dict:
         return {op: (self.calls[op], self.seconds[op]) for op in self.OPS}
@@ -96,11 +101,14 @@ class Window:
     plan: Plan
     seconds: float
     t0: float                    # host clock when the window opened
-    sent: np.ndarray
-    done: np.ndarray
+    sent: np.ndarray             # just before it was handed over
+    done: np.ndarray             # its answer in hand
     refused: np.ndarray          # the frontend would not take it
     error: np.ndarray            # answered with an error
-    answers: List[object]        # get: (rank, found); scan: (keys32, vals)
+    answers: List[object]        # as its kind's module keeps them
+    # of the errors, the frontend's `DeadlineExceeded` (its queue
+    # deadline passed): a failure, and no wrong answer
+    late: np.ndarray
 
     def answered_ok(self) -> np.ndarray:
         return ~self.refused & ~self.error & ~np.isnan(self.done)
@@ -113,43 +121,36 @@ class Window:
         return lat
 
 
-def _args(plan: Plan, i: int, page_size: int) -> Tuple[str, tuple]:
-    kind = KINDS[plan.kind[i]]
-    if kind == "get":
-        return kind, (plan.lo[i:i + 1],)
-    return kind, (float(plan.lo[i]), float(plan.hi[i]), page_size)
-
-
-def answer(kind: str, result):
-    """A request's answer as the client keeps it: scan rows on the host."""
-    if kind == "get":
-        rank, found = result
-        return int(rank[0]), bool(found[0])
-    keys, vals, live = jax.device_get(result)
-    return keys[live], vals[live]
-
-
-def send(fe, plan: Plan, idx, page_size: int) -> list:
+def send(fe, plan: Plan, ops: Dict[str, object], idx,
+         page_size: int) -> list:
     """Submit requests ``idx`` of ``plan`` at once (warm-up)."""
     out = []
     for i in idx:
-        kind, args = _args(plan, i, page_size)
-        out.append(fe.submit(TENANT, kind, *args))
+        kind = plan.kinds[plan.kind[i]]
+        out.append(fe.submit(TENANT, kind,
+                             *ops[kind].args(plan, i, page_size)))
     return out
 
 
 def drive(fe, plan: Plan, seconds: float, page_size: int,
+          ops: Dict[str, object],
           marks: Tuple[Tuple[float, Callable[[], None]], ...] = ()) -> Window:
     """Run one window of ``plan`` against the started frontend ``fe``.
     ``marks`` are (seconds after the open, action) pairs run on a helper
     thread, for starting and stopping a trace."""
+    from repro.serve.frontend import DeadlineExceeded
+
     n = plan.size
     sent = np.full(n, np.nan)
     done = np.full(n, np.nan)
     refused = np.zeros(n, bool)
     error = np.zeros(n, bool)
+    late = np.zeros(n, bool)
     answers: List[object] = [None] * n
     inbox: "queue.SimpleQueue" = queue.SimpleQueue()
+    names = plan.kinds
+    args_of = [ops[k].args if k in ops else None for k in names]
+    answer_of = {k: ops[k].answer for k in names if k in ops}
     t0 = time.perf_counter()
     close = t0 + seconds
 
@@ -164,9 +165,10 @@ def drive(fe, plan: Plan, seconds: float, page_size: int,
                 continue
             if req.error is not None:
                 error[i] = True
+                late[i] = isinstance(req.error, DeadlineExceeded)
                 done[i] = time.perf_counter() - t0
                 continue
-            answers[i] = answer(req.kind, req.result)
+            answers[i] = answer_of[req.kind](req.result)
             done[i] = time.perf_counter() - t0
 
     def run_marks():
@@ -186,18 +188,18 @@ def drive(fe, plan: Plan, seconds: float, page_size: int,
             time.sleep(min(due[i] - now, 0.002))
             continue
         while i < n and due[i] <= now:
-            kind, args = _args(plan, i, page_size)
+            k = plan.kind[i]
+            args = args_of[k](plan, i, page_size)
+            sent[i] = time.perf_counter() - t0
             try:
-                req = fe.submit(TENANT, kind, *args, timeout=0.0)
+                req = fe.submit(TENANT, names[k], *args, timeout=0.0)
             except RuntimeError:  # Backpressure or the ladder's refusals
                 refused[i] = True
-                sent[i] = time.perf_counter() - t0
             else:
-                sent[i] = time.perf_counter() - t0
                 inbox.put((i, req))
             i += 1
     inbox.put(None)
     collector.join()
     marker.join()
     return Window(plan=plan, seconds=seconds, t0=t0, sent=sent, done=done,
-                  refused=refused, error=error, answers=answers)
+                  refused=refused, error=error, answers=answers, late=late)

@@ -38,3 +38,32 @@ def roofline_pct(rec: dict, op: str) -> Optional[float]:
         return None
     least = moved / float(rec["peaks"]["hbm_bytes_per_s"])
     return 100.0 * least / rec["trace"]["busy_s"]
+
+
+def span_ms(rec: dict, path: str, own: bool = False) -> Optional[float]:
+    """Mean ms per span ``path`` (a span name or ``<parent>/<name>``) in
+    the traced window, its self time with ``own``; None where the trace
+    holds no such span."""
+    tr = rec.get("trace")
+    row = (tr or {}).get("spans", {}).get(path)
+    if not row or not row["count"]:
+        return None
+    return 1e3 * row["self_s" if own else "total_s"] / row["count"]
+
+
+def counter_ratio(rec: dict, num: str, den: str,
+                  scale: float = 1.0) -> Optional[float]:
+    """``scale`` x the window's change of counter ``num`` over that of
+    ``den``; None where ``den`` did not move."""
+    c = rec.get("counters", {})
+    if not c.get(den):
+        return None
+    return scale * c.get(num, 0) / c[den]
+
+
+def idle_pct(rec: dict, key: str) -> Optional[float]:
+    """100 x the trace's idle seconds ``key`` over the traced window."""
+    tr = rec.get("trace")
+    if not tr or not tr.get("busy_s") or not tr.get("window_s"):
+        return None
+    return 100.0 * tr[key] / tr["window_s"]

@@ -50,16 +50,15 @@ def main(argv=None) -> int:
     chips_or_exit(jax, cell.chips)
     from bench import harness
     from bench.metrics_util import tail_ms
-    from bench.traffic import make_plan
 
-    dep = harness.deploy(cell, args.seed)
+    dep = harness.deploy(cell, args.seed,
+                         tuple((args.seconds, r) for r in rates))
     slo = dep.fe.config.slo_p99_ms
     harness.log(f"setup {time.perf_counter() - T_START:.1f} s; slo p99 "
                 f"{slo} ms")
     knee, held = None, True
     for rate in rates:
-        plan = make_plan(cell.mix, dep.keys, args.seed, args.seconds,
-                         rate=rate)
+        plan, _ = harness.next_plan(dep, args.seed, args.seconds, rate=rate)
         win = harness.window(dep, plan, args.seconds)
         lat = win.latency()
         ok = win.answered_ok()

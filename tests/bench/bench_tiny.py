@@ -1,8 +1,8 @@
 """A copy of the benchmark's data at a size the CPU holds in a second:
-the real `BENCHMARK.json`, metric readers and peaks (with the CPU added
-as a device), configurations cut to a few thousand keys and a short
-frontend round, mixes at a low rate.  Runs skip the look for a chip and
-the persistent compile cache."""
+the real `BENCHMARK.json`, metric readers, request kinds, key generators
+and peaks (with the CPU added as a device), configurations cut to a few
+thousand keys and a short frontend round, mixes at a low rate.  Runs
+skip the look for a chip and the persistent compile cache."""
 
 from __future__ import annotations
 
@@ -25,7 +25,7 @@ def tiny_root(tmp: pathlib.Path, rate: float = 200.0) -> pathlib.Path:
     root = tmp / "checkout"
     (root / "bench").mkdir(parents=True)
     shutil.copy(REPO / "BENCHMARK.json", root)
-    for d in ("metrics", "configs", "traffic"):
+    for d in ("metrics", "configs", "traffic", "ops", "generators"):
         shutil.copytree(REPO / "bench" / d, root / "bench" / d,
                         ignore=shutil.ignore_patterns("__pycache__"))
     peaks = json.loads((REPO / "bench" / "peaks.json").read_text())

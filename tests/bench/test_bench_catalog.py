@@ -36,7 +36,10 @@ def test_cells_take_their_metrics_by_list():
     assert {m["name"] for m in cell.per_layer} == {
         "scan_p99_ms", "service.scan_ms", "snapshot.compiles.scan",
         "scan.device_us",
-        "scan_roofline", "device.idle_pct"}
+        "scan_roofline", "device.idle_pct", "frontend.queue_wait_ms",
+        "frontend.round_self_ms", "service.scan.prepare_ms",
+        "service.scan.dispatch_ms", "device.idle_host_pct",
+        "device.idle_unattributed_pct"}
     for m in cell.end_to_end + cell.per_layer:
         assert callable(catalog.load_reader(m["name"]))
 

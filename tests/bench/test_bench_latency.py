@@ -12,12 +12,14 @@ from bench.metrics_util import tail_ms
 def _window(done_after, seconds=10.0, n=5000, fail=()):
     due = np.linspace(0, seconds, n, endpoint=False)
     plan = loop.Plan(due=due, kind=np.zeros(n, np.int8), lo=due.copy(),
-                     hi=np.full(n, np.nan))
+                     hi=np.full(n, np.nan), val=np.full(n, -1, np.int64),
+                     kinds=("get",))
     error = np.zeros(n, bool)
     error[list(fail)] = True
     return loop.Window(plan=plan, seconds=seconds, t0=0.0, sent=due.copy(),
                        done=due + done_after, refused=np.zeros(n, bool),
-                       error=error, answers=[None] * n)
+                       error=error, answers=[None] * n,
+                       late=np.zeros(n, bool))
 
 
 def test_latency_runs_from_due_to_answer():

@@ -38,11 +38,21 @@ def test_sound_run_is_correct(tmp_path, monkeypatch, cell, metrics):
 def test_trace_run_reports_layer_metrics(tmp_path, monkeypatch):
     res = run(tiny_root(tmp_path), GET_CELL, monkeypatch, trace=True)
     assert res["correct"]
-    # the CPU has no TPU plane: the device metrics find nothing to read
+    # the CPU has no TPU plane: the device metrics find nothing to read;
+    # the program's spans and counters are read as on the chip
+    steps = {f"service.get.{s}_ms"
+             for s in ("prepare", "dispatch", "readback", "refine")}
     assert set(res["metrics"]) == {"read_p99_ms",
                                    "frontend.requests_per_round",
-                                   "service.get_ms", "snapshot.compiles.get"}
+                                   "service.get_ms", "snapshot.compiles.get",
+                                   "frontend.queue_wait_ms",
+                                   "frontend.round_self_ms",
+                                   "frontend.pad_share"} | steps
     assert res["metrics"]["snapshot.compiles.get"]["value"] == 0
+    m = {k: v["value"] for k, v in res["metrics"].items()}
+    assert all(m[k] > 0 for k in steps | {"frontend.queue_wait_ms",
+                                          "frontend.round_self_ms"})
+    assert 0 <= m["frontend.pad_share"] < 100
 
 
 def _alter_get(monkeypatch):

@@ -83,5 +83,7 @@ def test_gaps_are_named_by_the_open_host_annotation():
 
 
 def test_no_device_ops_reads_nothing():
-    assert trace_reduce.reduce_trace({"devices": {}, "host": []}) == {}
+    # no device program: the (empty) span table and no device figure
+    assert trace_reduce.reduce_trace({"devices": {}, "host": []}) == {
+        "spans": {}}
     assert catalog.load_reader("device.idle_pct")({"trace": {}}) is None

@@ -14,6 +14,7 @@ SCAN_MIX = {"ops": {"scan": 1.0}, "keys": {"dist": "latest", "theta": 0.99},
             "scan_rows": [1, 100], "arrival": {"process": "poisson"},
             "rate_ops_s": 500}
 KEYS = np.sort(np.random.default_rng(0).random(50_000)) * 1e6
+SPACE = traffic.KeySpace.of(KEYS)
 
 
 def test_zeta_matches_ycsb_constant():
@@ -23,9 +24,9 @@ def test_zeta_matches_ycsb_constant():
 
 
 def test_same_seed_same_plan_and_every_seed_the_same_work():
-    a = traffic.make_plan(GET_MIX, KEYS, 2**31 + 9, 4.0)
-    b = traffic.make_plan(GET_MIX, KEYS, 2**31 + 9, 4.0)
-    c = traffic.make_plan(GET_MIX, KEYS, 11, 4.0)
+    a = traffic.make_plan(GET_MIX, SPACE, 2**31 + 9, 4.0)
+    b = traffic.make_plan(GET_MIX, SPACE, 2**31 + 9, 4.0)
+    c = traffic.make_plan(GET_MIX, SPACE, 11, 4.0)
     assert np.array_equal(a.lo, b.lo) and np.array_equal(a.due, b.due)
     assert a.size == c.size == 4000
     assert not np.array_equal(a.lo, c.lo)
@@ -33,7 +34,7 @@ def test_same_seed_same_plan_and_every_seed_the_same_work():
 
 
 def test_zipfian_skew_and_scramble():
-    plan = traffic.make_plan(GET_MIX, KEYS, 3, 20.0)
+    plan = traffic.make_plan(GET_MIX, SPACE, 3, 20.0)
     _, counts = np.unique(plan.lo, return_counts=True)
     # a few hot keys take a large share; the hot keys are spread out
     top = np.sort(counts)[::-1]
@@ -44,7 +45,7 @@ def test_zipfian_skew_and_scramble():
 
 
 def test_latest_scans_hit_the_newest_keys():
-    plan = traffic.make_plan(SCAN_MIX, KEYS, 5, 4.0)
+    plan = traffic.make_plan(SCAN_MIX, SPACE, 5, 4.0)
     lo_i = np.searchsorted(KEYS, plan.lo)
     hi_i = np.searchsorted(KEYS, plan.hi)
     rows = hi_i - lo_i
@@ -55,10 +56,10 @@ def test_latest_scans_hit_the_newest_keys():
 
 def test_unknown_parameters_fail():
     with pytest.raises(ValueError):
-        traffic.make_plan(dict(GET_MIX, ops={"put": 1.0}), KEYS, 1, 1.0)
+        traffic.make_plan(dict(GET_MIX, ops={"put": 1.0}), SPACE, 1, 1.0)
     with pytest.raises(ValueError):
-        traffic.make_plan(dict(GET_MIX, keys={"dist": "uniform"}), KEYS, 1,
+        traffic.make_plan(dict(GET_MIX, keys={"dist": "uniform"}), SPACE, 1,
                           1.0)
     with pytest.raises(ValueError):
-        traffic.make_plan(dict(GET_MIX, arrival={"process": "closed"}), KEYS,
+        traffic.make_plan(dict(GET_MIX, arrival={"process": "closed"}), SPACE,
                           1, 1.0)
