@@ -26,9 +26,15 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.index_service.delta import combine_for_device, iter_levels
-from repro.index_service.scan import device_scan_slab
+from repro.index_service.scan import device_scan_slab, staged_scan_arrays
+from repro.obs import trace as obs_trace
+
+# the smallest staged-insert pad: an empty delta keeps it, so a
+# read-only deployment runs the scan program it always ran
+EMPTY_STAGED_PAD = 64
 
 
 def scan_plane_key(snap, frozen, active) -> tuple:
@@ -64,7 +70,19 @@ class DevicePlane:
       * the *scan slab* — staged-insert arrays + the prefix-sum page
         index `device_scan_slab` builds for the one-dispatch scan,
         keyed on `scan_plane_key` (identity + delta versions, so an
-        unchanged delta re-uses the upload outright).
+        unchanged delta re-uses the upload outright).  Its two parts
+        change at different rates: the O(n) page index depends only on
+        the snapshot and the tombstone positions, so a write version
+        that leaves those alone (an insert) re-packs and uploads only
+        the O(delta) staged-insert arrays (``plane.scan.update``) and
+        keeps the one device-resident page index; a new snapshot, new
+        tombstones or a `drop` rebuild both (``scan.pack_slab``).
+        Counters ``plane.scan.updates`` / ``.rebuilds`` /
+        ``.upload_bytes`` count the two paths and what they upload.
+
+    Staged-insert pads: ``staged_pad`` for any non-empty delta (the
+    service derives it from its compaction bound, so a growing delta
+    compiles no new scan program), `EMPTY_STAGED_PAD` for an empty one.
 
     Locking contract: `lookup_slab` and `cached_scan_slab` are called
     under the service lock (they read/publish one reference); the O(n)
@@ -76,12 +94,15 @@ class DevicePlane:
     # lixlint: thread-shared
     # lixlint: unsynchronized(cache publishes happen under the owning service lock; see locking contract above)
 
-    def __init__(self, metrics):
+    def __init__(self, metrics, staged_pad: int = EMPTY_STAGED_PAD):
         self._lookup = None  # (snap, dk, dp)
-        self._scan = None    # (key, slab, ins_n)
+        self._scan = None    # (key, slab, staged images)
+        self._live = None    # (snap, del_pos, device live_prefix)
+        self.staged_pad = staged_pad
         self._ctr = {
             k: metrics.counter(f"plane.{k}")
-            for k in ("lookup.hit", "lookup.miss", "scan.hit", "scan.miss")
+            for k in ("lookup.hit", "lookup.miss", "scan.hit", "scan.miss",
+                      "scan.updates", "scan.rebuilds", "scan.upload_bytes")
         }
 
     # ---- merged-lookup slab ---------------------------------------------
@@ -100,10 +121,10 @@ class DevicePlane:
         return cache[1], cache[2]
 
     # ---- fused-scan slab -------------------------------------------------
-    def cached_scan_slab(self, key: tuple) -> Optional[Tuple[tuple, int]]:
-        """(slab, ins_n) when the cached plane matches ``key``, else
-        None (the caller then pins a view and calls `build_scan_slab`
-        outside the lock)."""
+    def cached_scan_slab(self, key: tuple) -> Optional[Tuple[tuple, np.ndarray]]:
+        """(slab, staged images) when the cached plane matches ``key``,
+        else None (the caller then pins a view and calls
+        `build_scan_slab` outside the lock)."""
         plane = self._scan
         if plane is not None and scan_plane_key_eq(plane[0], key):
             self._ctr["scan.hit"].add(1)
@@ -111,15 +132,42 @@ class DevicePlane:
         self._ctr["scan.miss"].add(1)
         return None
 
+    def staged_min_pad(self, staged: int) -> int:
+        """The least pad of the staged-insert arrays for ``staged``
+        effective inserts."""
+        return self.staged_pad if staged else EMPTY_STAGED_PAD
+
     def build_scan_slab(self, key: tuple, view, norm, normalize):
-        """Pack + upload the scan plane for an immutable pinned view
-        and publish it under ``key``.  Publishing is one reference
-        write; concurrent builders at worst race to publish equivalent
-        slabs."""
-        ins, ivals, ins_rank, lp = device_scan_slab(view, norm, normalize)
-        slab = tuple(jnp.asarray(a) for a in (ins, ivals, ins_rank, lp))
-        self._scan = (key, slab, view.ins_keys.size)
-        return slab, view.ins_keys.size
+        """The scan plane of an immutable pinned view, published under
+        ``key`` (whose first entry is the view's snapshot): the
+        staged-insert arrays re-packed and uploaded against the cached
+        page index where that was built for the same snapshot and
+        tombstone positions, else everything rebuilt.  Returns the
+        slab and the staged inserts' float32 images (host, sorted).
+        Publishing is one reference write; concurrent builders at worst
+        race to publish equivalent slabs."""
+        snap, k = key[0], view.ins_keys.size
+        pad = self.staged_min_pad(k)
+        live = self._live
+        if (live is not None and live[0] is snap
+                and np.array_equal(live[1], view.del_pos)):
+            with obs_trace.span("plane.scan.update", cat="plane",
+                                staged=int(k)):
+                host = staged_scan_arrays(view, norm, normalize,
+                                          min_pad=pad)
+                slab = tuple(jnp.asarray(a) for a in host) + (live[2],)
+            self._ctr["scan.updates"].add(1)
+        else:
+            # drop the old page index first: one on the device at a time
+            self._scan = self._live = None
+            host = device_scan_slab(view, norm, normalize, min_pad=pad)
+            slab = tuple(jnp.asarray(a) for a in host)
+            self._live = (snap, view.del_pos, slab[3])
+            self._ctr["scan.rebuilds"].add(1)
+        self._ctr["scan.upload_bytes"].add(sum(a.nbytes for a in host))
+        staged = host[0][:k]
+        self._scan = (key, slab, staged)
+        return slab, staged
 
     # ---- invalidation ----------------------------------------------------
     def drop_lookup(self) -> None:
@@ -131,3 +179,4 @@ class DevicePlane:
         surfaces (also releases the retired arrays' device buffers)."""
         self._lookup = None
         self._scan = None
+        self._live = None

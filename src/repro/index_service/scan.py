@@ -23,9 +23,11 @@ values — without ever materializing the merged array:
     staged-insert arrays plus the prefix-sum page index
     (``live_prefix``, ``ins_rank``) that lets the kernel rank the
     endpoints and resolve rank→row with single-gather fixed-trip
-    searches.  Built once per (snapshot, delta) version and cached by
-    the services; quarter-pow2 pad buckets (`_pad_bucket`) key the jit
-    cache per capacity bucket, never per write.
+    searches.  Cached by the services; the page index is rebuilt only
+    when the snapshot or the tombstones change, the staged-insert
+    arrays (`staged_scan_arrays`) per delta version; quarter-pow2 pad
+    buckets (`_pad_bucket`) key the jit cache per capacity bucket,
+    never per write.
   * `device_scan_plan` — the older rank-addressed lowering for
     `kernels.ops.rmi_scan_page_op` (still the building block for
     callers that already hold ranks).
@@ -307,25 +309,42 @@ def device_scan_slab(
         (ins_norm f32 (+inf pad), ins_vals i32, ins_rank i32,
          live_prefix i32 (n+1,))
 
-    ``ins_rank[j] = j + live_prefix[lower_bound(base_norm, ins[j])]``
-    is staged insert j's merged rank, precomputed in the SAME float32
-    frame the kernel searches (``base_norm``), so the device partition
-    is internally consistent with the device select even where float32
-    normalization collides.  Built once per (snapshot, delta version)
-    and cached by the service — the per-scan host cost of the old path
-    (collapse + re-pack per call) amortizes to zero on the read path.
+    the staged-insert arrays of `staged_scan_arrays` plus the
+    prefix-sum page index over the base (`live_prefix_index`).  The
+    full build: the page index costs O(n) on the host and depends only
+    on the snapshot and the tombstones, so the device plane rebuilds it
+    only when those change and otherwise re-packs the staged-insert
+    arrays alone.
 
-    Pads go to quarter-pow2 buckets (`_pad_bucket`), keying the jit
-    cache per capacity bucket, never per write.
+    Pads go to quarter-pow2 buckets (`_pad_bucket`) at or above
+    ``min_pad``, keying the jit cache per capacity bucket, never per
+    write.
     """
     from repro.obs import trace as obs_trace
     with obs_trace.span(
         "scan.pack_slab", cat="plane", staged=int(view.ins_keys.size)
     ):
-        return _device_scan_slab_inner(view, base_norm, normalize, min_pad)
+        ins, ivals, ins_rank = staged_scan_arrays(
+            view, base_norm, normalize, min_pad=min_pad)
+        lp = live_prefix_index(view.del_pos, view.base_keys.size)
+        return ins, ivals, ins_rank, lp
 
 
-def _device_scan_slab_inner(view, base_norm, normalize, min_pad):
+def staged_scan_arrays(
+    view: PinnedView, base_norm: np.ndarray, normalize, *,
+    min_pad: int = 64,
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The staged-insert arrays of the scan plane, O(staged) on the
+    host: ``(ins_norm f32 (+inf pad), ins_vals i32, ins_rank i32)``.
+
+    ``ins_rank[j] = j + live_prefix[lower_bound(base_norm, ins[j])]``
+    is staged insert j's merged rank, taken in the SAME float32 frame
+    the kernel searches (``base_norm``), with ``live_prefix[p] = p -
+    #tombstoned positions < p`` read off the sorted ``del_pos`` instead
+    of the O(n) page index.  A lower bound ranks an insert before every
+    base key whose image it ties, and the kernel's select prefers the
+    insert on such a tie (`kernels.rmi_lookup._scan_rows_from_index`).
+    """
     k = view.ins_keys.size
     pad_i = _pad_bucket(k + 1, min_pad=min_pad)
     ins = np.full(pad_i, np.inf, np.float32)
@@ -334,12 +353,12 @@ def _device_scan_slab_inner(view, base_norm, normalize, min_pad):
     ivals[:k] = np.clip(
         view.ins_vals, np.iinfo(np.int32).min, np.iinfo(np.int32).max
     )
-    lp = live_prefix_index(view.del_pos, view.base_keys.size)
     ins_rank = np.full(pad_i, _RANK_PAD, np.int32)
     if k:
         bl = np.searchsorted(base_norm, ins[:k], side="left")
-        ins_rank[:k] = np.arange(k, dtype=np.int32) + lp[bl]
-    return ins, ivals, ins_rank, lp
+        dead = np.searchsorted(view.del_pos, bl, side="left")
+        ins_rank[:k] = np.arange(k) + bl - dead
+    return ins, ivals, ins_rank
 
 
 def fit_scan_frame(views) -> Tuple[float, float]:
@@ -363,7 +382,8 @@ def scan_page_bound(
     raws, ins_total: int, lo: float, hi: float, page_size: int
 ) -> int:
     """Conservative static page count for a fused device scan of
-    [lo, hi): per-array base windows plus every staged insert can only
+    [lo, hi): per-array base windows plus ``ins_total`` staged inserts
+    (every one, or those whose image lies in the range) can only
     over-count rows (tombstones shrink), bucketed for jit-cache
     stability.  Host metadata sizing the output shape — NOT a rank fed
     to the device program.  One extra page of slack covers the device
@@ -396,17 +416,9 @@ def pack_scan_slab(
             view.base_vals, np.iinfo(np.int32).min, np.iinfo(np.int32).max
         )
     lp = live_prefix_index(view.del_pos, n, n_pad=n_pad)
-    k = view.ins_keys.size
-    ins = np.full(d_pad, np.inf, np.float32)
-    ins[:k] = normalize(view.ins_keys)
-    ivals = np.zeros(d_pad, np.int32)
-    ivals[:k] = np.clip(
-        view.ins_vals, np.iinfo(np.int32).min, np.iinfo(np.int32).max
-    )
-    ins_rank = np.full(d_pad, _RANK_PAD, np.int32)
-    if k:
-        bl = np.searchsorted(base[:n], ins[:k], side="left")
-        ins_rank[:k] = np.arange(k, dtype=np.int32) + lp[bl]
+    # d_pad is a pad bucket, so the staged arrays come out at exactly it
+    ins, ivals, ins_rank = staged_scan_arrays(
+        view, base[:n], normalize, min_pad=d_pad)
     return {
         "base": base, "bvals": bvals, "live_prefix": lp,
         "ins": ins, "ivals": ivals, "ins_rank": ins_rank,

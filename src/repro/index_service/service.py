@@ -57,6 +57,7 @@ from repro.index_service.plane import (
 )
 from repro.index_service.scan import (
     PinnedView,
+    _pad_bucket,
     pin_view,
     scan_page_bound,
     scan_pages,
@@ -106,6 +107,16 @@ class ServiceConfig:
     compact_max_failures: int = 3
     compact_backoff_s: float = 0.05
     compact_backoff_cap_s: float = 2.0
+
+
+def _staged_scan_pad(cfg: ServiceConfig) -> int:
+    """The scan plane's staged-insert pad for a non-empty delta: the
+    bucket past the most effective inserts the levels can hold between
+    compactions (every frozen level and the active one full), so the
+    scan program a delta's growth needs is the one the first staged
+    write already compiled."""
+    most = (max(1, cfg.max_delta_levels) + 1) * cfg.delta_capacity
+    return _pad_bucket(most + 1)
 
 
 def _default_rmi(n: int) -> RMIConfig:
@@ -204,7 +215,8 @@ class IndexService:
         # every device-resident mirror (lookup slab + scan plane) lives
         # behind this boundary; orchestration only captures state and
         # signals invalidation (see plane.DevicePlane)
-        self._plane = DevicePlane(self.metrics)
+        self._plane = DevicePlane(
+            self.metrics, staged_pad=_staged_scan_pad(cfg))
         # legacy dict surface, now a live view over registry counters
         self.stats = StatsView(self.metrics, "svc", _STATS_KEYS)
         self._op_hist = {
@@ -434,11 +446,14 @@ class IndexService:
     def _scan_plane_cached(self):
         """The device-resident scan plane for the current (snapshot,
         delta) version: staged-insert arrays plus the prefix-sum page
-        index (`scan.device_scan_slab`), packed and uploaded once per
-        version and reused by every `scan_batch` until the next write
-        or compaction — keyed on (snapshot identity, delta identity +
-        mutation version), so the read path never re-collapses or
-        re-uploads an unchanged delta."""
+        index (`scan.device_scan_slab`), reused by every `scan_batch`
+        until the next write or compaction — keyed on (snapshot
+        identity, delta identity + mutation version), so the read path
+        never re-collapses or re-uploads an unchanged delta.  A write
+        that leaves the tombstones alone re-packs only the staged
+        inserts (`plane.DevicePlane.build_scan_slab`).  Returns
+        ``(snap, slab, staged)``, ``staged`` the staged inserts'
+        float32 images (host, sorted)."""
         with self._lock:
             snap, frozen, active = (
                 self._mgr.current(), self._frozen, self._active
@@ -448,13 +463,13 @@ class IndexService:
             if hit is not None:
                 return snap, hit[0], hit[1]
             view = pin_view(snap, frozen, active)
-        # the O(n) index build + upload run OUTSIDE the lock (the
-        # pinned view is immutable), so writers and compaction commits
-        # don't stall behind it
-        slab, ins_n = self._plane.build_scan_slab(
+        # the re-pack and upload run OUTSIDE the lock (the pinned view
+        # is immutable), so writers and compaction commits don't stall
+        # behind them
+        slab, staged = self._plane.build_scan_slab(
             key, view, snap.keys.norm, snap.keys.normalize
         )
-        return snap, slab, ins_n
+        return snap, slab, staged
 
     def scan_batch(self, lo: float, hi: float, page_size: int = 256):
         """Device fast path for scans: ONE device program — endpoint
@@ -469,26 +484,27 @@ class IndexService:
 
         Returns ``(keys (G, page_size) f32, vals i32, live_mask)`` in
         the snapshot's *normalized float32 frame* with int32 values;
-        pages past the range come back fully masked.  Exact whenever
-        float32 normalization is injective over the base+delta keys
-        (now including the range endpoints), the same caveat as
-        `lookup_batch`; `scan` is the guaranteed-exact float64
-        surface."""
+        pages past the range come back fully masked.  Rows are the live
+        keys whose image lies in [f32(lo), f32(hi)), in merge order
+        except that a staged insert comes before the base keys whose
+        image it ties; `scan` is the exact float64 surface."""
         t0 = time.perf_counter()
         with obs_trace.span("service.scan_batch", cat="service"):
             with obs_trace.span("service.prepare", cat="service"):
-                snap, (ins, ivals, ins_rank, lp), ins_n = (
+                snap, (ins, ivals, ins_rank, lp), staged = (
                     self._scan_plane_cached())
+                # a host array: the scan program's call uploads it
+                bounds = snap.keys.normalize(np.array([lo, hi], np.float64))
                 # static output-shape bound (host metadata sizing the
                 # output, not a rank fed to the device; see
-                # scan.scan_page_bound)
+                # scan.scan_page_bound), over the staged inserts whose
+                # image lies in the range
+                s0, s1 = np.searchsorted(staged, bounds)
                 pages = scan_page_bound(
-                    [snap.keys.raw], ins_n, lo, hi, page_size
+                    [snap.keys.raw], int(s1 - s0), lo, hi, page_size
                 )
                 fn = snap.scan_range_fn(self.config.strategy, page_size,
                                         pages)
-                # a host array: the scan program's call uploads it
-                bounds = snap.keys.normalize(np.array([lo, hi], np.float64))
             with obs_trace.span("service.dispatch", cat="service"):
                 out = fn(bounds, ins, ivals, ins_rank, lp)
         dt = time.perf_counter() - t0

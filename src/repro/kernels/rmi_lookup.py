@@ -267,8 +267,15 @@ def _scan_page_body(
          where a_before(x) = lower_bound(base, x) - dead_before;
       2. select:     the (t-j)-th live base row by binary search over
          base positions with live_before(p) = p - lower_bound(del_pos, p);
-      3. emit        min(A[t-j], C[j]) with its source's value; slots
-         at or past ``end_rank`` are masked dead (+inf key, 0 value).
+      3. emit        C[j] where C[j] <= A[t-j], else A[t-j], with its
+         source's value; slots at or past ``end_rank`` are masked dead
+         (+inf key, 0 value).
+
+    The tie rule: ``a_before`` is a float32 lower bound, so the
+    partition ranks a staged insert before every base key whose image
+    it ties, and the select must then prefer the insert on a tie, or
+    it emits the tied base row at the insert's rank and again at the
+    next one.
 
     Fixed trip counts everywhere, so the same body runs inside the
     Pallas kernel and the XLA fallback with bit-identical results.
@@ -313,7 +320,7 @@ def _scan_page_body(
     c_key = jnp.where(j >= ni, inf, ins_keys.read(jnp.clip(j, 0, ni - 1)))
     c_val = ins_vals.read(jnp.clip(j, 0, ni - 1))
 
-    from_ins = c_key < a_key
+    from_ins = c_key <= a_key   # the tie rule (docstring)
     live = ((t >= 0) & (t < end_rank)).astype(jnp.int32)
     key = jnp.where(from_ins, c_key, a_key)
     val = jnp.where(from_ins, c_val, a_val)
@@ -368,11 +375,15 @@ def _scan_rows_from_index(
          insert j, strictly increasing, HOST-precomputed;
       2. select:     the (t-j)-th live base row via one lower bound
          over the monotone ``live_prefix`` array;
-      3. emit        min(base row, insert row) with its source's value;
-         rows with ``valid`` False are masked dead (+inf key, 0 val).
+      3. emit        the insert row where its key is at most the base
+         row's, else the base row, with its source's value; rows with
+         ``valid`` False are masked dead (+inf key, 0 val).
 
     Decomposition identical to `_scan_page_body` (same j, same base
-    position, same min rule), so rows match the NumPy merge oracle.
+    position, same tie rule: ``ins_rank`` counts base rows below an
+    insert by a float32 lower bound, so an insert that ties a base
+    key's image ranks before it, and the select prefers the insert on
+    a tie), so rows match the NumPy merge oracle.
     """
     inf = jnp.float32(jnp.inf)
     n, ni = base_keys.size, ins_keys.size
@@ -392,7 +403,7 @@ def _scan_rows_from_index(
                           ins_keys.read(jnp.clip(j, 0, ni - 1)))
         c_val = ins_vals.read(jnp.clip(j, 0, ni - 1))
 
-        from_ins = c_key < a_key
+        from_ins = c_key <= a_key   # the tie rule (docstring)
         live = jnp.asarray(valid).astype(jnp.int32)
         key = jnp.where(from_ins, c_key, a_key)
         val = jnp.where(from_ins, c_val, a_val)

@@ -303,7 +303,7 @@ def _spans(log_dir, prefixes=("frontend.", "service.", "dispatch.")):
     return out
 
 
-def _traced(tmp_path, body, enable=True):
+def _traced(tmp_path, body, enable=True, **spans_kw):
     """Run ``body`` under a profiler trace (the program's spans on while
     ``enable``) and return the spans that reached it."""
     import jax
@@ -316,7 +316,7 @@ def _traced(tmp_path, body, enable=True):
     finally:
         obs_trace.TRACER.disable()
         jax.profiler.stop_trace()
-    return _spans(str(tmp_path))
+    return _spans(str(tmp_path), **spans_kw)
 
 
 def _inside(inner, outer):
@@ -389,6 +389,28 @@ def test_scan_batch_spans_split_prepare_and_dispatch(tmp_path):
     (op,) = [s for s in spans if s[0] == "dispatch.rmi_scan_range"]
     assert _inside(prep, scan) and _inside(disp, scan)
     assert prep[2] <= disp[1] and _inside(op, disp)
+
+
+def test_insert_then_scan_updates_the_plane_in_a_span(tmp_path):
+    base = _lattice()
+    svc = IndexService(base, ServiceConfig(delta_capacity=256),
+                       vals=np.arange(base.size))
+    lo, hi = float(base[3]), float(base[90])
+    svc.insert(np.array([float(base[5]) + 512.0]), np.array([-1]))
+    svc.scan_batch(lo, hi, 64)   # full build and compile outside the trace
+
+    def insert_then_scan():
+        svc.insert(np.array([float(base[7]) + 512.0]), np.array([-2]))
+        svc.scan_batch(lo, hi, 64)
+    spans = _traced(tmp_path, insert_then_scan,
+                    prefixes=("plane.", "scan.", "service."))
+    (update,) = [s for s in spans if s[0] == "plane.scan.update"]
+    (prep,) = [s for s in spans if s[0] == "service.prepare"]
+    assert _inside(update, prep) and update[4]["staged"] == 2
+    # the page index was kept: no full build in the trace
+    assert not [s for s in spans if s[0] == "scan.pack_slab"]
+    assert svc.metrics.counter("plane.scan.updates").value == 1
+    assert svc.metrics.counter("plane.scan.rebuilds").value == 1
 
 
 def test_idle_dispatcher_waits_in_a_span_and_instants_are_points(tmp_path):

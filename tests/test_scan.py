@@ -518,3 +518,201 @@ def test_paged_kv_scan_batch_one_dispatch_matches_scan():
     host_k, host_v = _concat(alloc.scan(lo, hi, 64))
     np.testing.assert_array_equal(got_k, alloc.scan_normalize(host_k))
     np.testing.assert_array_equal(got_v, host_v.astype(np.int32))
+
+
+# --------------------------------------------------------------------------
+# staged inserts whose float32 image ties a base key's
+# --------------------------------------------------------------------------
+
+def _frame_merge(snap, base, bvals, ins, ivals, lo, hi):
+    """The plain sorted merge of live base rows and staged inserts in
+    the snapshot's float32 frame: rows whose image lies in [f32(lo),
+    f32(hi)), ordered by image, a staged insert before the base keys it
+    ties, and by key within each source."""
+    keys = np.concatenate([ins, base])
+    vals = np.concatenate([ivals, bvals])
+    staged = np.concatenate([np.zeros(ins.size), np.ones(base.size)])
+    img = snap.keys.normalize(keys)
+    order = np.lexsort((keys, staged, img))
+    img, vals = img[order], vals[order]
+    a, b = snap.keys.normalize(np.array([lo, hi], np.float64))
+    m = (img >= a) & (img < b)
+    return img[m], vals[m].astype(np.int32)
+
+
+def _tie_case(case):
+    """(base, base values, inserts, insert values, base keys deleted,
+    scan ranges) with inserts that tie base keys in float32."""
+    if case == "witness":
+        base = np.linspace(0, 1000, 20001)
+        ins = 1000 + np.array([1e-5, 2e-5, 3e-5])
+        return (base, np.arange(base.size), ins,
+                np.arange(20001, 20004), np.empty(0),
+                [(float(base[-5]), 1001.00003)])
+    rng = np.random.default_rng(23)
+    # a dense run of keys 1e-6 apart is about 60 keys a float32 image
+    # in a frame 1000 wide: at the top (newest-order inserts) and in
+    # the middle, staged inserts interleave with it
+    at = 1000.0 if case == "top" else 500.0
+    run = at - 4e-4 + np.arange(400) * 1e-6
+    base = np.unique(np.concatenate([rng.uniform(0, 1000, 4000), run,
+                                     [0.0, 1000.0]]))
+    ins = np.setdiff1d(np.unique(run[:-1] + rng.uniform(1e-7, 9e-7, 399)
+                                 + (4e-4 if case == "top" else 0.0)), base)
+    dead = rng.choice(run, 30, replace=False)
+    lo, hi = float(run[40]), float(run[-1]) + (8e-4 if case == "top"
+                                                else 0.0)
+    return (base, np.arange(base.size) * 3, ins,
+            10_000 + np.arange(ins.size), dead,
+            [(lo, hi), (float(run[0]), float(run[120])),
+             (float(run[200]), float(run[200]) + 5e-6)])
+
+
+@pytest.mark.parametrize("strategy", ["binary", "pallas_fused"])
+@pytest.mark.parametrize("case", ["witness", "top", "middle"])
+def test_scan_batch_merges_inserts_that_tie_base_keys(case, strategy):
+    """A staged insert whose float32 image ties a base key's is merged
+    once, and the tied base row once: `scan_batch` equals the plain
+    merge in the float32 frame (no row twice, none left out)."""
+    base, bvals, ins, ivals, dead, ranges = _tie_case(case)
+    svc = IndexService(base, ServiceConfig(strategy=strategy), vals=bvals)
+    svc.insert(ins, ivals)
+    if dead.size:
+        svc.delete(dead)
+    snap = svc._mgr.current()
+    img = snap.keys.normalize(np.concatenate([base, ins]))
+    assert np.unique(img).size < img.size   # the case does tie
+    live = ~np.isin(base, dead)
+    for lo, hi in ranges:
+        keys, vals, mask = (np.asarray(a) for a in
+                            svc.scan_batch(lo, hi, 256))
+        want_k, want_v = _frame_merge(snap, base[live], bvals[live], ins,
+                                      ivals, lo, hi)
+        np.testing.assert_array_equal(keys[mask], want_k)
+        np.testing.assert_array_equal(vals[mask], want_v)
+    if case == "witness":
+        got = np.sort(vals[mask])
+        np.testing.assert_array_equal(got, np.arange(19996, 20004))
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_rank_addressed_scan_pages_merge_inserts_that_tie(use_kernel):
+    """The older rank-addressed scan body keeps the same tie rule: its
+    pages over every merged rank are the plain merge in the float32
+    frame."""
+    from repro.index_service.scan import device_scan_plan, pin_view
+
+    base, bvals, ins, ivals, dead, _ = _tie_case("middle")
+    svc = IndexService(base, ServiceConfig(), vals=bvals)
+    svc.insert(ins, ivals)
+    svc.delete(dead)
+    snap = svc._mgr.current()
+    view = pin_view(snap, svc._frozen, svc._active)
+    dins, divals, dpos = device_scan_plan(view, snap.keys.normalize)
+    end = view.live_count
+    starts = np.arange(0, end, 1024, dtype=np.int32)
+    keys, vals, live = (np.asarray(a) for a in ops.rmi_scan_page_op(
+        starts, snap.keys.norm, bvals.astype(np.int32), dins, divals, dpos,
+        np.array([end], np.int32), page_size=1024, use_kernel=use_kernel))
+    live = live.astype(bool)
+    alive = ~np.isin(base, dead)
+    want_k, want_v = _frame_merge(snap, base[alive], bvals[alive], ins,
+                                  ivals, base[0], np.inf)
+    np.testing.assert_array_equal(keys[live], want_k)
+    np.testing.assert_array_equal(vals[live], want_v)
+
+
+# --------------------------------------------------------------------------
+# the incremental scan plane
+# --------------------------------------------------------------------------
+
+def _plane_steps(seed):
+    """A seeded sequence of write versions: inserts between them, one
+    delete of base keys, one delete of staged keys only, one freeze,
+    one compaction; with the plane path each is expected to take."""
+    rng = np.random.default_rng(seed)
+    steps = [("insert", "update") for _ in range(int(rng.integers(2, 5)))]
+    steps.insert(int(rng.integers(1, len(steps) + 1)),
+                 ("delete base", "rebuild"))
+    steps += [("delete staged", "update"), ("insert", "update"),
+              ("freeze", "rebuild"), ("insert", "update")]
+    steps += [("insert", "update")] * int(rng.integers(1, 3))
+    steps += [("compact", "rebuild"), ("insert", "update")]
+    return steps
+
+
+@pytest.mark.parametrize("seed", [3, 2**31 + 19, 2**33 + 5])
+def test_incremental_scan_plane_matches_a_full_build(seed):
+    """After every write version the plane the service serves equals a
+    from-scratch `device_scan_slab` of the same view bit for bit, and
+    its counters count exactly the full builds and the updates: a write
+    that leaves the snapshot and the tombstones alone only re-packs the
+    staged inserts."""
+    from repro.index_service.scan import device_scan_slab, pin_view
+
+    rng = np.random.default_rng(seed)
+    base = np.unique(rng.integers(0, 1 << 40, 3000).astype(np.float64))
+    svc = IndexService(base, ServiceConfig(delta_capacity=256,
+                                           max_delta_levels=2),
+                       vals=np.arange(base.size))
+    plane, reg = svc._plane, svc.metrics
+    staged = []
+
+    def count(name):
+        return reg.counter(f"plane.scan.{name}").value
+
+    svc.scan_batch(float(base[10]), float(base[90]), 64)   # the first build
+    want = {"rebuilds": 1, "updates": 0}
+    for i, (step, path) in enumerate(_plane_steps(seed)):
+        if step == "insert":
+            fresh = np.setdiff1d(np.unique(
+                rng.integers(0, 1 << 40, 20).astype(np.float64)), base)
+            svc.insert(fresh, np.arange(fresh.size) + 10_000 * (i + 1))
+            staged += list(fresh)
+        elif step == "delete base":
+            svc.delete(rng.choice(base, 5, replace=False))
+        elif step == "delete staged":
+            svc.delete(np.array(staged[-3:]))
+        elif step == "freeze":
+            assert svc.maybe_compact() and svc.num_delta_levels == 1
+        else:
+            svc.flush()
+        want[path + "s"] += 1
+        snap, slab, imgs = svc._scan_plane_cached()
+        view = pin_view(snap, svc._frozen, svc._active)
+        full = device_scan_slab(
+            view, snap.keys.norm, snap.keys.normalize,
+            min_pad=plane.staged_min_pad(view.ins_keys.size))
+        for got, ref_arr in zip(slab, full):
+            got = np.asarray(got)
+            assert got.dtype == ref_arr.dtype and got.shape == ref_arr.shape
+            np.testing.assert_array_equal(got, ref_arr)
+        np.testing.assert_array_equal(
+            imgs, snap.keys.normalize(view.ins_keys))
+        assert {k: count(k) for k in want} == want, (i, step)
+        # the served plane answers as the exact host scan does
+        lo, hi = float(base[200]), float(base[2800])
+        keys, _, mask = (np.asarray(a) for a in svc.scan_batch(lo, hi, 256))
+        host_k, _ = _concat(svc.scan(lo, hi, 4096))
+        np.testing.assert_array_equal(keys[mask],
+                                      snap.keys.normalize(host_k))
+    upload = count("upload_bytes")
+    assert upload > 0 and count("updates") >= 5
+
+
+def test_non_empty_delta_scans_at_one_pad():
+    """Every non-empty delta up to the compaction bound scans through
+    one staged-insert pad, so a growing delta compiles no new scan
+    program; an empty delta keeps the smallest pad."""
+    base = np.arange(2, 4002, dtype=np.float64) * 8.0
+    svc = IndexService(base, ServiceConfig(delta_capacity=512),
+                       vals=np.arange(base.size))
+    pads = set()
+    for k in (0, 1, 40, 300, 380):
+        if k:
+            fresh = np.arange(k, dtype=np.float64) * 8.0 + 3.0 + 8.0 * k
+            svc.insert(fresh[~np.isin(fresh, base)])
+        _, (ins, *_), _ = svc._scan_plane_cached()
+        pads.add((k > 0, ins.shape[0]))
+    assert pads == {(False, 64), (True, svc._plane.staged_pad)}
+    assert svc._plane.staged_pad >= 2 * 512 + 1

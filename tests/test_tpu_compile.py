@@ -143,6 +143,26 @@ def test_fused_scan_op_program_compiles(one_chip, op):
         fn, args, dict(page_size=PAGE, use_kernel=True, **static))
 
 
+@pytest.mark.parametrize("staged", ["empty", "non-empty"])
+def test_cells_scan_program_compiles_at_their_size(one_chip, staged):
+    """The scan program the benchmark's cells run (strategy ``binary``:
+    the XLA body) over 200M keys, at the staged-insert pad of an empty
+    delta and at the one pad every non-empty delta of a default service
+    scans at."""
+    from repro.index_service.plane import EMPTY_STAGED_PAD
+    from repro.index_service.service import ServiceConfig, _staged_scan_pad
+    from repro.kernels import ops
+
+    n = 200_000_000
+    d = (EMPTY_STAGED_PAD if staged == "empty"
+         else _staged_scan_pad(ServiceConfig()))
+    args = _shapes(one_chip, ((2,), F32), ((n,), F32), ((n,), I32),
+                   ((n + 1,), I32), ((d,), F32), ((d,), I32), ((d,), I32))
+    ops._scan_range_jit.lower(
+        *args, page_size=PAGE, max_pages=2, use_kernel=False,
+        interpret=False).compile()
+
+
 def test_sharded_scan_page_compiles(one_chip):
     ns = N // S
     args = _shapes(one_chip, ((S, ns), F32), ((S, ns), I32),
