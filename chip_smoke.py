@@ -334,16 +334,16 @@ def kernel_key_limit() -> int:
             sds(s, d + 1, dt=i32), sds(s, dt=i32), sds(s, dt=i32), sds(s),
         ), dict(hidden=(), max_window=256)
         yield rl.rmi_scan_range_pallas, (
-            sds(2), sds(n), sds(n, dt=i32), sds(n + 1, dt=i32), sds(d),
-            sds(d, dt=i32), sds(d, dt=i32),
+            sds(2), sds(n), sds(n, dt=i32), sds(d), sds(d, dt=i32),
+            sds(d, dt=i32), sds(d, dt=i32), sds(d, dt=i32),
         ), dict(page_size=PAGE, max_pages=16)
         yield rl.rmi_scan_page_pallas, (
             sds(16, dt=i32), sds(n), sds(n, dt=i32), sds(d),
             sds(d, dt=i32), sds(d, dt=i32), sds(1, dt=i32),
         ), dict(page_size=PAGE)
         yield rl.rmi_sharded_scan_page_pallas, (
-            sds(s, ns), sds(s, ns, dt=i32), sds(s, ns + 1, dt=i32),
-            sds(s, d), sds(s, d, dt=i32), sds(s, d, dt=i32),
+            sds(s, ns), sds(s, ns, dt=i32), sds(s, d), sds(s, d, dt=i32),
+            sds(s, d, dt=i32), sds(s, d, dt=i32),
             sds(s, dt=i32), sds(s, dt=i32), sds(s, dt=i32),
         ), dict(page_size=PAGE, max_pages=16)
 

@@ -23,17 +23,19 @@ level invalidates every plane consistently.
 
 from __future__ import annotations
 
+import weakref
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 
 from repro.index_service.delta import combine_for_device, iter_levels
-from repro.index_service.scan import device_scan_slab, staged_scan_arrays
+from repro.index_service.scan import staged_scan_arrays, tombstone_scan_arrays
 from repro.obs import trace as obs_trace
 
-# the smallest staged-insert pad: an empty delta keeps it, so a
-# read-only deployment runs the scan program it always ran
+# the smallest pad of the scan plane's delta-sized arrays: an empty
+# delta keeps it, so a read-only deployment runs the scan program it
+# always ran
 EMPTY_STAGED_PAD = 64
 
 
@@ -67,25 +69,25 @@ class DevicePlane:
         packs for the merged-lookup kernel, keyed on snapshot identity
         (writes drop it explicitly via `drop_lookup`, so the key never
         needs to read delta state);
-      * the *scan slab* — staged-insert arrays + the prefix-sum page
-        index `device_scan_slab` builds for the one-dispatch scan,
-        keyed on `scan_plane_key` (identity + delta versions, so an
-        unchanged delta re-uses the upload outright).  Its two parts
-        change at different rates: the O(n) page index depends only on
-        the snapshot and the tombstone positions, so a write version
-        that leaves those alone (an insert) re-packs and uploads only
-        the O(delta) staged-insert arrays (``plane.scan.update``) and
-        keeps the one device-resident page index; a new snapshot, new
-        tombstones or a `drop` rebuild both (``scan.pack_slab``).
-        Counters ``plane.scan.updates`` / ``.rebuilds`` /
-        ``.upload_bytes`` count the two paths and what they upload.
+      * the *scan slab* — the staged-insert arrays
+        (`staged_scan_arrays`) and the tombstone arrays
+        (`tombstone_scan_arrays`) of the one-dispatch scan, keyed on
+        `scan_plane_key` (identity + delta versions, so an unchanged
+        delta re-uses the upload outright).  Every build packs and
+        uploads only arrays the size of the delta, O(delta) on the host:
+        no array of the plane is sized by the base.  Counters
+        ``plane.scan.updates`` (builds for a new delta version of the
+        snapshot the last build served, span ``plane.scan.update``),
+        ``.rebuilds`` (builds for a new snapshot, span
+        ``scan.pack_slab``), ``.upload_bytes`` and ``.tombstones`` (the
+        dead base positions packed).
 
-    Staged-insert pads: ``staged_pad`` for any non-empty delta (the
-    service derives it from its compaction bound, so a growing delta
-    compiles no new scan program), `EMPTY_STAGED_PAD` for an empty one.
+    Pads: ``staged_pad`` for a non-empty array (the service derives it
+    from its compaction bound, so a growing delta compiles no new scan
+    program), `EMPTY_STAGED_PAD` for an empty one.
 
     Locking contract: `lookup_slab` and `cached_scan_slab` are called
-    under the service lock (they read/publish one reference); the O(n)
+    under the service lock (they read/publish one reference);
     `build_scan_slab` runs OUTSIDE the lock on an immutable pinned
     view, so writers and compaction commits never stall behind a
     re-pack — a plane made stale by a concurrent write just misses its
@@ -97,12 +99,15 @@ class DevicePlane:
     def __init__(self, metrics, staged_pad: int = EMPTY_STAGED_PAD):
         self._lookup = None  # (snap, dk, dp)
         self._scan = None    # (key, slab, staged images)
-        self._live = None    # (snap, del_pos, device live_prefix)
+        # the snapshot of the last scan build, held weakly: a retired
+        # snapshot's device buffers are not kept alive by the plane
+        self._scan_snap = None
         self.staged_pad = staged_pad
         self._ctr = {
             k: metrics.counter(f"plane.{k}")
             for k in ("lookup.hit", "lookup.miss", "scan.hit", "scan.miss",
-                      "scan.updates", "scan.rebuilds", "scan.upload_bytes")
+                      "scan.updates", "scan.rebuilds", "scan.upload_bytes",
+                      "scan.tombstones")
         }
 
     # ---- merged-lookup slab ---------------------------------------------
@@ -132,41 +137,36 @@ class DevicePlane:
         self._ctr["scan.miss"].add(1)
         return None
 
-    def staged_min_pad(self, staged: int) -> int:
-        """The least pad of the staged-insert arrays for ``staged``
-        effective inserts."""
-        return self.staged_pad if staged else EMPTY_STAGED_PAD
+    def staged_min_pad(self, count: int) -> int:
+        """The least pad of a delta-sized scan array that holds
+        ``count`` entries (effective inserts, or tombstones)."""
+        return self.staged_pad if count else EMPTY_STAGED_PAD
 
     def build_scan_slab(self, key: tuple, view, norm, normalize):
         """The scan plane of an immutable pinned view, published under
-        ``key`` (whose first entry is the view's snapshot): the
-        staged-insert arrays re-packed and uploaded against the cached
-        page index where that was built for the same snapshot and
-        tombstone positions, else everything rebuilt.  Returns the
-        slab and the staged inserts' float32 images (host, sorted).
-        Publishing is one reference write; concurrent builders at worst
-        race to publish equivalent slabs."""
-        snap, k = key[0], view.ins_keys.size
-        pad = self.staged_min_pad(k)
-        live = self._live
-        if (live is not None and live[0] is snap
-                and np.array_equal(live[1], view.del_pos)):
-            with obs_trace.span("plane.scan.update", cat="plane",
-                                staged=int(k)):
-                host = staged_scan_arrays(view, norm, normalize,
-                                          min_pad=pad)
-                slab = tuple(jnp.asarray(a) for a in host) + (live[2],)
-            self._ctr["scan.updates"].add(1)
-        else:
-            # drop the old page index first: one on the device at a time
-            self._scan = self._live = None
-            host = device_scan_slab(view, norm, normalize, min_pad=pad)
+        ``key`` (whose first entry is the view's snapshot): ``(ins_norm,
+        ins_vals, ins_rank, dead_pos, dead_rank)`` packed and uploaded,
+        O(delta).  Returns the slab and the staged inserts' float32
+        images (host, sorted).  Publishing is one reference write;
+        concurrent builders at worst race to publish equivalent
+        slabs."""
+        snap, k, m = key[0], view.ins_keys.size, view.del_pos.size
+        last = self._scan_snap
+        update = last is not None and last() is snap
+        with obs_trace.span("plane.scan.update" if update
+                            else "scan.pack_slab", cat="plane",
+                            staged=int(k), dead=int(m)):
+            host = staged_scan_arrays(
+                view, norm, normalize, min_pad=self.staged_min_pad(k)
+            ) + tombstone_scan_arrays(
+                view.del_pos, min_pad=self.staged_min_pad(m))
             slab = tuple(jnp.asarray(a) for a in host)
-            self._live = (snap, view.del_pos, slab[3])
-            self._ctr["scan.rebuilds"].add(1)
+        self._ctr["scan.updates" if update else "scan.rebuilds"].add(1)
         self._ctr["scan.upload_bytes"].add(sum(a.nbytes for a in host))
+        self._ctr["scan.tombstones"].add(int(m))
         staged = host[0][:k]
         self._scan = (key, slab, staged)
+        self._scan_snap = weakref.ref(snap)
         return slab, staged
 
     # ---- invalidation ----------------------------------------------------
@@ -179,4 +179,3 @@ class DevicePlane:
         surfaces (also releases the retired arrays' device buffers)."""
         self._lookup = None
         self._scan = None
-        self._live = None

@@ -17,17 +17,17 @@ values — without ever materializing the merged array:
     tombstone-filtered base slice and one insert slice merge into the
     next ``page_size`` rows (O(page + tombstones-in-window + log n)
     per page, vs O(n log n) for re-merging the whole key set).
-  * `device_scan_slab` / `pack_scan_slab` / `live_prefix_index` — a
-    view lowered to the FUSED device scan's inputs
+  * `staged_scan_arrays` / `tombstone_scan_arrays` / `pack_scan_slab`
+    — a view lowered to the FUSED device scan's inputs
     (`kernels.ops.rmi_scan_range_op` / `rmi_sharded_scan_page_op`):
-    staged-insert arrays plus the prefix-sum page index
-    (``live_prefix``, ``ins_rank``) that lets the kernel rank the
+    the staged inserts with their merged ranks (``ins_rank``) and the
+    tombstoned base positions with the live rows below each
+    (``dead_pos``, ``dead_rank``), which let the kernel rank the
     endpoints and resolve rank→row with single-gather fixed-trip
-    searches.  Cached by the services; the page index is rebuilt only
-    when the snapshot or the tombstones change, the staged-insert
-    arrays (`staged_scan_arrays`) per delta version; quarter-pow2 pad
-    buckets (`_pad_bucket`) key the jit cache per capacity bucket,
-    never per write.
+    searches over delta-sized arrays.  O(delta) on the host per delta
+    version; cached by the services; quarter-pow2 pad buckets
+    (`_pad_bucket`) key the jit cache per capacity bucket, never per
+    write.
   * `device_scan_plan` — the older rank-addressed lowering for
     `kernels.ops.rmi_scan_page_op` (still the building block for
     callers that already hold ranks).
@@ -272,62 +272,10 @@ def _pad_bucket(x: int, *, min_pad: int = 64) -> int:
     return p
 
 
-# pad value for `ins_rank` slots past the staged-insert count: larger
-# than any reachable merged rank (int32-safe), so the partition search
-# never selects a pad
+# pad value for the slots past the true count of `ins_rank`,
+# `dead_pos` and `dead_rank`: larger than any reachable merged rank or
+# base position (int32-safe), so no search ever counts a pad
 _RANK_PAD = np.int32(1 << 30)
-
-
-def live_prefix_index(
-    del_pos: np.ndarray, n: int, *, n_pad: Optional[int] = None
-) -> np.ndarray:
-    """The prefix-sum page index over base positions:
-    ``live_prefix[p] = p - #tombstoned positions < p`` — i.e. how many
-    LIVE base rows sit below position p.  Monotone, so the device scan
-    resolves rank -> base row (and base position -> rank) with one
-    fixed-trip binary search instead of a nested tombstone search per
-    trip.  Padded (when ``n_pad`` is given) by repeating the final
-    value, which pins searches past the true size."""
-    mark = np.zeros(n + 1, np.int64)
-    if del_pos.size:
-        mark[np.asarray(del_pos, np.int64) + 1] = 1
-    lp = np.arange(n + 1, dtype=np.int64) - np.cumsum(mark)
-    if n_pad is None or n_pad == n:
-        return lp.astype(np.int32)
-    out = np.full(n_pad + 1, lp[-1], np.int32)
-    out[: n + 1] = lp
-    return out
-
-
-def device_scan_slab(
-    view: PinnedView, base_norm: np.ndarray, normalize, *,
-    min_pad: int = 64,
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Lower a pinned view's delta side to the fused-endpoint scan
-    inputs `kernels.ops.rmi_scan_range_op` consumes:
-
-        (ins_norm f32 (+inf pad), ins_vals i32, ins_rank i32,
-         live_prefix i32 (n+1,))
-
-    the staged-insert arrays of `staged_scan_arrays` plus the
-    prefix-sum page index over the base (`live_prefix_index`).  The
-    full build: the page index costs O(n) on the host and depends only
-    on the snapshot and the tombstones, so the device plane rebuilds it
-    only when those change and otherwise re-packs the staged-insert
-    arrays alone.
-
-    Pads go to quarter-pow2 buckets (`_pad_bucket`) at or above
-    ``min_pad``, keying the jit cache per capacity bucket, never per
-    write.
-    """
-    from repro.obs import trace as obs_trace
-    with obs_trace.span(
-        "scan.pack_slab", cat="plane", staged=int(view.ins_keys.size)
-    ):
-        ins, ivals, ins_rank = staged_scan_arrays(
-            view, base_norm, normalize, min_pad=min_pad)
-        lp = live_prefix_index(view.del_pos, view.base_keys.size)
-        return ins, ivals, ins_rank, lp
 
 
 def staged_scan_arrays(
@@ -337,13 +285,13 @@ def staged_scan_arrays(
     """The staged-insert arrays of the scan plane, O(staged) on the
     host: ``(ins_norm f32 (+inf pad), ins_vals i32, ins_rank i32)``.
 
-    ``ins_rank[j] = j + live_prefix[lower_bound(base_norm, ins[j])]``
+    ``ins_rank[j] = j + live_before(lower_bound(base_norm, ins[j]))``
     is staged insert j's merged rank, taken in the SAME float32 frame
-    the kernel searches (``base_norm``), with ``live_prefix[p] = p -
-    #tombstoned positions < p`` read off the sorted ``del_pos`` instead
-    of the O(n) page index.  A lower bound ranks an insert before every
-    base key whose image it ties, and the kernel's select prefers the
-    insert on such a tie (`kernels.rmi_lookup._scan_rows_from_index`).
+    the kernel searches (``base_norm``), with ``live_before(p) = p -
+    #tombstoned positions < p`` read off the sorted ``del_pos``.  A
+    lower bound ranks an insert before every base key whose image it
+    ties, and the kernel's select prefers the insert on such a tie
+    (`kernels.rmi_lookup._scan_rows`).
     """
     k = view.ins_keys.size
     pad_i = _pad_bucket(k + 1, min_pad=min_pad)
@@ -359,6 +307,29 @@ def staged_scan_arrays(
         dead = np.searchsorted(view.del_pos, bl, side="left")
         ins_rank[:k] = np.arange(k) + bl - dead
     return ins, ivals, ins_rank
+
+
+def tombstone_scan_arrays(
+    del_pos: np.ndarray, *, min_pad: int = 64
+) -> Tuple[np.ndarray, np.ndarray]:
+    """The tombstone arrays of the scan plane, O(tombstones) on the
+    host: ``(dead_pos i32, dead_rank i32)``, both padded with
+    `_RANK_PAD`.
+
+    ``dead_pos`` is the sorted dead base positions d_0 < d_1 < ...;
+    ``dead_rank[j] = d_j - j`` is how many LIVE base rows lie below d_j
+    (nondecreasing).  The kernel ranks an endpoint with ``bl -
+    lower_bound(dead_pos, bl)`` and finds the a-th live base row at
+    ``a + upper_bound(dead_rank, a)`` (`kernels.rmi_lookup._merged_rank`,
+    `_scan_rows`), so no array the size of the base is needed.
+    """
+    m = del_pos.size
+    pad = _pad_bucket(m + 1, min_pad=min_pad)
+    dead_pos = np.full(pad, _RANK_PAD, np.int32)
+    dead_rank = np.full(pad, _RANK_PAD, np.int32)
+    dead_pos[:m] = del_pos
+    dead_rank[:m] = del_pos - np.arange(m)
+    return dead_pos, dead_rank
 
 
 def fit_scan_frame(views) -> Tuple[float, float]:
@@ -397,16 +368,29 @@ def scan_page_bound(
     return _pad_bucket(-(-max(1, span) // page_size) + 1, min_pad=1)
 
 
+# the stacked per-shard arrays of a sharded scan plane, in the order
+# `kernels.ops.rmi_sharded_scan_page_op` takes them after ``bounds``
+SLAB_ROWS = ("base", "bvals", "ins", "ivals", "ins_rank", "dead_pos",
+             "dead_rank")
+
+
+def delta_size(view: PinnedView) -> int:
+    """The entries a view's delta-sized scan arrays hold: its staged
+    inserts or its tombstones, whichever are more — one ``d_pad``
+    bucket covers both."""
+    return max(view.ins_keys.size, view.del_pos.size)
+
+
 def pack_scan_slab(
     view: PinnedView, normalize, n_pad: int, d_pad: int
 ) -> dict:
     """One shard's stacked-scan slab row for
-    `kernels.ops.rmi_sharded_scan_page_op`: the `device_scan_slab`
-    layout padded to the fleet-wide ``(n_pad, d_pad)`` bucket, with the
-    base keys re-normalized into the SHARED frame ``normalize`` (shard
-    ranges tile the key space, so one global affine frame keeps
-    cross-shard rows comparable).  Returns a dict of per-row arrays
-    plus the shard's live row count."""
+    `kernels.ops.rmi_sharded_scan_page_op`: the base keys and values
+    padded to the fleet-wide ``n_pad`` bucket and re-normalized into the
+    SHARED frame ``normalize`` (shard ranges tile the key space, so one
+    global affine frame keeps cross-shard rows comparable), and the
+    staged-insert and tombstone arrays at the ``d_pad`` bucket: a dict
+    of the `SLAB_ROWS` arrays."""
     n = view.base_keys.size
     base = np.full(n_pad, np.inf, np.float32)
     base[:n] = normalize(view.base_keys)
@@ -415,14 +399,14 @@ def pack_scan_slab(
         bvals[:n] = np.clip(
             view.base_vals, np.iinfo(np.int32).min, np.iinfo(np.int32).max
         )
-    lp = live_prefix_index(view.del_pos, n, n_pad=n_pad)
-    # d_pad is a pad bucket, so the staged arrays come out at exactly it
+    # d_pad is a pad bucket, so the delta arrays come out at exactly it
     ins, ivals, ins_rank = staged_scan_arrays(
         view, base[:n], normalize, min_pad=d_pad)
+    dead_pos, dead_rank = tombstone_scan_arrays(view.del_pos, min_pad=d_pad)
     return {
-        "base": base, "bvals": bvals, "live_prefix": lp,
+        "base": base, "bvals": bvals,
         "ins": ins, "ivals": ivals, "ins_rank": ins_rank,
-        "live": view.live_count,
+        "dead_pos": dead_pos, "dead_rank": dead_rank,
     }
 
 
@@ -438,7 +422,7 @@ def stack_scan_slabs(views) -> dict:
     same `pack_scan_slab` rows."""
     lo, hi = fit_scan_frame(views)
     n_pad = _pad_bucket(max(v.base_keys.size for v in views) + 1)
-    d_pad = _pad_bucket(max(v.ins_keys.size for v in views) + 1)
+    d_pad = _pad_bucket(max(delta_size(v) for v in views) + 1)
 
     def normalize(x):
         return (
@@ -450,12 +434,7 @@ def stack_scan_slabs(views) -> dict:
         "lo": lo, "hi": hi, "normalize": normalize,
         "raws": [v.base_keys for v in views],
         "ins_total": int(sum(v.ins_keys.size for v in views)),
-        "base": np.stack([r["base"] for r in rows]),
-        "bvals": np.stack([r["bvals"] for r in rows]),
-        "live_prefix": np.stack([r["live_prefix"] for r in rows]),
-        "ins": np.stack([r["ins"] for r in rows]),
-        "ivals": np.stack([r["ivals"] for r in rows]),
-        "ins_rank": np.stack([r["ins_rank"] for r in rows]),
+        **{k: np.stack([r[k] for r in rows]) for k in SLAB_ROWS},
     }
 
 

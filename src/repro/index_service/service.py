@@ -445,13 +445,12 @@ class IndexService:
 
     def _scan_plane_cached(self):
         """The device-resident scan plane for the current (snapshot,
-        delta) version: staged-insert arrays plus the prefix-sum page
-        index (`scan.device_scan_slab`), reused by every `scan_batch`
-        until the next write or compaction — keyed on (snapshot
-        identity, delta identity + mutation version), so the read path
-        never re-collapses or re-uploads an unchanged delta.  A write
-        that leaves the tombstones alone re-packs only the staged
-        inserts (`plane.DevicePlane.build_scan_slab`).  Returns
+        delta) version: the staged-insert and tombstone arrays
+        (`plane.DevicePlane.build_scan_slab`, O(delta)), reused by every
+        `scan_batch` until the next write or compaction — keyed on
+        (snapshot identity, delta identity + mutation version), so the
+        read path never re-collapses or re-uploads an unchanged delta.
+        Returns
         ``(snap, slab, staged)``, ``staged`` the staged inserts'
         float32 images (host, sorted)."""
         with self._lock:
@@ -491,8 +490,7 @@ class IndexService:
         t0 = time.perf_counter()
         with obs_trace.span("service.scan_batch", cat="service"):
             with obs_trace.span("service.prepare", cat="service"):
-                snap, (ins, ivals, ins_rank, lp), staged = (
-                    self._scan_plane_cached())
+                snap, slab, staged = self._scan_plane_cached()
                 # a host array: the scan program's call uploads it
                 bounds = snap.keys.normalize(np.array([lo, hi], np.float64))
                 # static output-shape bound (host metadata sizing the
@@ -506,7 +504,7 @@ class IndexService:
                 fn = snap.scan_range_fn(self.config.strategy, page_size,
                                         pages)
             with obs_trace.span("service.dispatch", cat="service"):
-                out = fn(bounds, ins, ivals, ins_rank, lp)
+                out = fn(bounds, *slab)
         dt = time.perf_counter() - t0
         self.stats["scan_batch"] += 1
         self.stats["scan_batch_s"] += dt

@@ -48,12 +48,12 @@ device-plane cache:
   * `scan_batch` runs the stacked scan twin
     (`rmi_sharded_scan_page_pallas`): a fused rank pre-pass turns the
     per-shard spans of [lo, hi) into stream ownership, the grid kernel
-    gathers each shard's rows through its prefix-sum page index, and
-    an owner-masked reduction emits the global page stream;
+    gathers each shard's rows through its staged-insert and tombstone
+    ranks, and an owner-masked reduction emits the global page stream;
   * both plans cache per shard on (snapshot identity, delta version):
     a write re-PACKS only its own shard's slab row (collapse,
-    normalize, prefix-index, live count) into persistent host mirrors
-    — PR 4 rebuilt every row on every write; the stacked device
+    normalize, staged-insert and tombstone arrays) into persistent host
+    mirrors — PR 4 rebuilt every row on every write; the stacked device
     buffers then refresh in one bulk transfer (device-side per-row
     `.at[s].set` updates are the real-TPU follow-on).
 """
@@ -79,7 +79,9 @@ from repro.index_service.delta import (
 )
 from repro.index_service.router import LearnedRouter
 from repro.index_service.scan import (
+    SLAB_ROWS,
     _pad_bucket,
+    delta_size,
     fit_scan_frame,
     pack_scan_slab,
     repack_pages,
@@ -206,21 +208,11 @@ class _ScanPlane:
     hi: float
     n_pad: int
     d_pad: int
-    rows: list                 # per-shard pack_scan_slab dicts (+ sizes)
+    rows: list                 # per-shard true sizes
     raws: list                 # per-shard base raw arrays (sizing bounds)
     ins_total: int
-    base: jnp.ndarray          # (S, Npad) f32 +inf padded, shared frame
-    bvals: jnp.ndarray         # (S, Npad) i32
-    live_prefix: jnp.ndarray   # (S, Npad+1) i32
-    ins: jnp.ndarray           # (S, Dpad) f32
-    ivals: jnp.ndarray         # (S, Dpad) i32
-    ins_rank: jnp.ndarray      # (S, Dpad) i32
-    base_np: np.ndarray        # host mirrors of the six stacks
-    bvals_np: np.ndarray
-    lp_np: np.ndarray
-    ins_np: np.ndarray
-    ivals_np: np.ndarray
-    irank_np: np.ndarray
+    host: dict                 # SLAB_ROWS name -> (S, Npad | Dpad) mirror
+    dev: dict                  # SLAB_ROWS name -> device array
 
     def normalize(self, x) -> np.ndarray:
         """Raw float64 keys -> the plane's shared float32 frame (the
@@ -668,8 +660,7 @@ class ShardedIndexService:
                 plane.normalize(np.array([lo, hi], np.float64))
             )
             out = kernels_ops.rmi_sharded_scan_page_op(
-                bounds, plane.base, plane.bvals, plane.live_prefix,
-                plane.ins, plane.ivals, plane.ins_rank,
+                bounds, *(plane.dev[k] for k in SLAB_ROWS),
                 page_size=page_size, max_pages=pages,
                 use_kernel=self.config.strategy in _KERNEL_STRATEGIES,
                 strategy=self.config.strategy,
@@ -692,10 +683,10 @@ class ShardedIndexService:
 
     def _scan_plane(self) -> _ScanPlane:
         """The incremental stacked scan plane: per-shard slabs (base
-        keys re-normalized into one shared frame, prefix-sum page
-        index, staged-insert arrays) cached per (snapshot, delta
-        version) — a write to one shard re-packs ONE slab row; the
-        frame and every other row are reused, and a delta-only change
+        keys re-normalized into one shared frame, staged-insert and
+        tombstone arrays) cached per (snapshot, delta version) — a
+        write to one shard re-packs ONE slab row; the frame and every
+        other row are reused, and a delta-only change
         skips re-uploading the (much larger) base/bvals stacks.  A
         rebalance (new shard services) or a pad-bucket change rebuilds
         from scratch.
@@ -731,7 +722,7 @@ class ShardedIndexService:
                 for s in range(len(svcs))
             ]
             sizes_d = [
-                pins[s].ins_keys.size if s in pins else old.rows[s]["d"]
+                delta_size(pins[s]) if s in pins else old.rows[s]["d"]
                 for s in range(len(svcs))
             ]
             n_pad = _pad_bucket(max(sizes_n) + 1)
@@ -742,7 +733,8 @@ class ShardedIndexService:
                 # arrays are copies, see the upload note below); base keys
                 # and payloads only change when a shard's SNAPSHOT moved
                 plane = dataclasses.replace(
-                    old, rows=list(old.rows), raws=list(old.raws)
+                    old, rows=list(old.rows), raws=list(old.raws),
+                    dev=dict(old.dev),
                 )
                 snap_dirty = any(
                     old.key[s][0] is not keys[s][0] for s in changed
@@ -760,7 +752,7 @@ class ShardedIndexService:
                     max(v.base_keys.size for v in pins.values()) + 1
                 )
                 d_pad = _pad_bucket(
-                    max(v.ins_keys.size for v in pins.values()) + 1
+                    max(delta_size(v) for v in pins.values()) + 1
                 )
                 lo, hi = fit_scan_frame([pins[s] for s in changed])
                 s_count = len(svcs)
@@ -768,14 +760,7 @@ class ShardedIndexService:
                     key=(), shards_key=tuple(svcs),
                     lo=float(lo), hi=float(hi), n_pad=n_pad, d_pad=d_pad,
                     rows=[None] * s_count, raws=[None] * s_count, ins_total=0,
-                    base=None, bvals=None, live_prefix=None, ins=None,
-                    ivals=None, ins_rank=None,
-                    base_np=np.full((s_count, n_pad), np.inf, np.float32),
-                    bvals_np=np.zeros((s_count, n_pad), np.int32),
-                    lp_np=np.zeros((s_count, n_pad + 1), np.int32),
-                    ins_np=np.full((s_count, d_pad), np.inf, np.float32),
-                    ivals_np=np.zeros((s_count, d_pad), np.int32),
-                    irank_np=np.zeros((s_count, d_pad), np.int32),
+                    host={}, dev={},
                 )
                 snap_dirty = True
             for s in changed:
@@ -783,29 +768,25 @@ class ShardedIndexService:
                 row = pack_scan_slab(view, plane.normalize, n_pad, d_pad)
                 # keep only the true sizes — the arrays live in the mirrors
                 plane.rows[s] = {
-                    "n": view.base_keys.size, "d": view.ins_keys.size,
+                    "n": view.base_keys.size, "d": delta_size(view),
+                    "ins": view.ins_keys.size,
                 }
                 plane.raws[s] = view.base_keys
-                plane.base_np[s] = row["base"]
-                plane.bvals_np[s] = row["bvals"]
-                plane.lp_np[s] = row["live_prefix"]
-                plane.ins_np[s] = row["ins"]
-                plane.ivals_np[s] = row["ivals"]
-                plane.irank_np[s] = row["ins_rank"]
-            plane.ins_total = int(sum(r["d"] for r in plane.rows))
+                for k in SLAB_ROWS:
+                    if k not in plane.host:  # a full rebuild's first row
+                        plane.host[k] = np.empty(
+                            (len(svcs),) + row[k].shape, row[k].dtype)
+                    plane.host[k][s] = row[k]
+            plane.ins_total = int(sum(r["ins"] for r in plane.rows))
             # jnp.array (copy=True): jnp.asarray can zero-copy ALIAS a f32
             # NumPy buffer on the CPU backend, and these mirrors mutate in
             # place on the next incremental build — an aliased upload would
             # corrupt device arrays still referenced from earlier calls.
             # Delta-only changes reuse the old base/bvals device arrays
             # outright (the dominant transfer for large indexes).
-            if snap_dirty:
-                plane.base = jnp.array(plane.base_np)
-                plane.bvals = jnp.array(plane.bvals_np)
-            plane.live_prefix = jnp.array(plane.lp_np)
-            plane.ins = jnp.array(plane.ins_np)
-            plane.ivals = jnp.array(plane.ivals_np)
-            plane.ins_rank = jnp.array(plane.irank_np)
+            for k in SLAB_ROWS:
+                if snap_dirty or k not in ("base", "bvals"):
+                    plane.dev[k] = jnp.array(plane.host[k])
             plane.key = tuple(keys)
             self._scan_cache = plane  # atomic publish of the finished plane
             return plane

@@ -408,8 +408,8 @@ class IndexSnapshot:
         self, strategy: str = "binary", page_size: int = 256,
         max_pages: int = 1,
     ) -> Callable:
-        """jit fn (bounds, ins_keys, ins_vals, ins_rank, live_prefix)
-        -> (keys (max_pages, page_size) f32, vals i32, live_mask bool)
+        """jit fn (bounds, ins_keys, ins_vals, ins_rank, dead_pos,
+        dead_rank) -> (keys (max_pages, page_size) f32, vals i32, live_mask bool)
         — the FUSED scan read path: the merged ranks of ``bounds =
         [lo, hi)``, every page start, every row gather and the live
         mask's bool cast all happen inside one device program
@@ -417,9 +417,9 @@ class IndexSnapshot:
         kernel strategies, the bit-identical XLA program otherwise).
         Nothing ranks on the host;
         ``max_pages`` is only the static output-shape bound (pages past
-        the range come back masked).  Delta inputs come from
-        `scan.device_scan_slab`, cached by the service per (snapshot,
-        delta version).  Same float32/int32 exactness caveat as
+        the range come back masked).  Delta inputs come from the scan
+        plane (`plane.DevicePlane.build_scan_slab`), cached by the
+        service per (snapshot, delta version).  Same float32/int32 exactness caveat as
         `lookup_batch` — host `IndexService.scan` is the exact float64
         surface."""
         validate_strategy(strategy)
@@ -429,10 +429,11 @@ class IndexSnapshot:
         if fn is None:
             base_norm, bvals = self._device_base()
 
-            def fn(bounds, ins_keys, ins_vals, ins_rank, live_prefix):
+            def fn(bounds, ins_keys, ins_vals, ins_rank, dead_pos,
+                   dead_rank):
                 return kernels_ops.rmi_scan_range_op(
-                    bounds, base_norm, bvals, live_prefix, ins_keys,
-                    ins_vals, ins_rank,
+                    bounds, base_norm, bvals, ins_keys, ins_vals, ins_rank,
+                    dead_pos, dead_rank,
                     page_size=page_size, max_pages=max_pages,
                     use_kernel=use_kernel, strategy=strategy,
                 )

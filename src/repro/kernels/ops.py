@@ -28,7 +28,7 @@ from repro.kernels.bloom_probe import bloom_probe_pallas
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.hash_probe import hash_probe_pallas
 from repro.kernels.rmi_lookup import (
-    _merged_rank_from_prefix,
+    _merged_rank,
     _search_steps,
     _xla,
     rmi_lookup_pallas,
@@ -641,8 +641,8 @@ def _scan_page_jit(
 
 
 def rmi_scan_range_op(
-    bounds, base_keys, base_vals, live_prefix, ins_keys, ins_vals,
-    ins_rank, *, page_size=256, max_pages=1, use_kernel=True,
+    bounds, base_keys, base_vals, ins_keys, ins_vals, ins_rank, dead_pos,
+    dead_rank, *, page_size=256, max_pages=1, use_kernel=True,
     interpret=None, strategy=None,
 ):
     """Fused endpoint-ranking + paged merged-scan gather: ONE device
@@ -652,18 +652,18 @@ def rmi_scan_range_op(
 
     The successor to `rmi_scan_page_op` for the service scan path: no
     host rank feeds the program — ranks, page starts, and rows all
-    resolve on device through the prefix-sum page index
-    (``live_prefix``, ``ins_rank``, precomputed per (snapshot, delta)
-    version by `index_service.scan.device_scan_slab`).  ``max_pages``
-    is a conservative *shape* bound (base window + staged inserts);
-    pages past the true range come back fully masked.  Kernel and XLA
-    fallback share the same body — bit-identical for every input.
-    Inputs may be host or device arrays: the dtype casts run inside
-    the program (`_scan_range_jit`), so a host ``bounds`` array is
-    uploaded by the call itself.
+    resolve on device through delta-sized arrays (``ins_rank``,
+    ``dead_pos``, ``dead_rank``, precomputed per delta version by
+    `index_service.scan.staged_scan_arrays` / `tombstone_scan_arrays`).
+    ``max_pages`` is a conservative *shape* bound (base window + staged
+    inserts); pages past the true range come back fully masked.  Kernel
+    and XLA fallback share the same body — bit-identical for every
+    input.  Inputs may be host or device arrays: the dtype casts run
+    inside the program (`_scan_range_jit`), so a host ``bounds`` array
+    is uploaded by the call itself.
     """
-    args = (bounds, base_keys, base_vals, live_prefix, ins_keys, ins_vals,
-            ins_rank)
+    args = (bounds, base_keys, base_vals, ins_keys, ins_vals, ins_rank,
+            dead_pos, dead_rank)
 
     def run_fallback():
         with dispatch_span("rmi_scan_range", kernel=False, strategy=strategy):
@@ -692,16 +692,16 @@ def rmi_scan_range_op(
     static_argnames=("page_size", "max_pages", "use_kernel", "interpret"),
 )
 def _scan_range_jit(
-    bounds, base_keys, base_vals, live_prefix, ins_keys, ins_vals,
-    ins_rank, *, page_size, max_pages, use_kernel, interpret,
+    bounds, base_keys, base_vals, ins_keys, ins_vals, ins_rank, dead_pos,
+    dead_rank, *, page_size, max_pages, use_kernel, interpret,
 ):
     # the dtype contract lives in the trace: a cast to the dtype an
     # argument already has is no operation
     args = (
         bounds.astype(jnp.float32), base_keys.astype(jnp.float32),
-        base_vals.astype(jnp.int32), live_prefix.astype(jnp.int32),
-        ins_keys.astype(jnp.float32), ins_vals.astype(jnp.int32),
-        ins_rank.astype(jnp.int32),
+        base_vals.astype(jnp.int32), ins_keys.astype(jnp.float32),
+        ins_vals.astype(jnp.int32), ins_rank.astype(jnp.int32),
+        dead_pos.astype(jnp.int32), dead_rank.astype(jnp.int32),
     )
     if use_kernel:
         keys, vals, live = rmi_scan_range_pallas(
@@ -716,8 +716,8 @@ def _scan_range_jit(
 
 
 def rmi_sharded_scan_page_op(
-    bounds, base_keys, base_vals, live_prefix, ins_keys, ins_vals,
-    ins_rank, *, page_size=256, max_pages=1, use_kernel=True,
+    bounds, base_keys, base_vals, ins_keys, ins_vals, ins_rank, dead_pos,
+    dead_rank, *, page_size=256, max_pages=1, use_kernel=True,
     interpret=None, strategy=None,
 ):
     """Sharded fused scan: ONE device dispatch ranks ``bounds`` on
@@ -735,10 +735,11 @@ def rmi_sharded_scan_page_op(
         jnp.asarray(bounds, jnp.float32),
         jnp.asarray(base_keys, jnp.float32),
         jnp.asarray(base_vals, jnp.int32),
-        jnp.asarray(live_prefix, jnp.int32),
         jnp.asarray(ins_keys, jnp.float32),
         jnp.asarray(ins_vals, jnp.int32),
         jnp.asarray(ins_rank, jnp.int32),
+        jnp.asarray(dead_pos, jnp.int32),
+        jnp.asarray(dead_rank, jnp.int32),
     )
 
     def run_fallback():
@@ -772,40 +773,40 @@ def rmi_sharded_scan_page_op(
     static_argnames=("page_size", "max_pages", "use_kernel", "interpret"),
 )
 def _sharded_scan_jit(
-    bounds, base_keys, base_vals, live_prefix, ins_keys, ins_vals,
-    ins_rank, *, page_size, max_pages, use_kernel, interpret,
+    bounds, base_keys, base_vals, ins_keys, ins_vals, ins_rank, dead_pos,
+    dead_rank, *, page_size, max_pages, use_kernel, interpret,
 ):
     steps = _search_steps(base_keys.shape[1])
     isteps = _search_steps(ins_keys.shape[1])
+    tsteps = _search_steps(dead_pos.shape[1])
 
     # rank pre-pass: each shard's local ranks of [lo, hi) — all keys in
     # lower shards sort below both bounds, so the per-shard spans
     # concatenate into the global stream and their prefix sums are the
     # ownership offsets (same program, no host round-trip)
-    def rank_one(base, lp, ins):
-        return _merged_rank_from_prefix(
-            bounds, _xla(base), _xla(lp), _xla(ins), steps=steps,
-            isteps=isteps,
+    def rank_one(base, dpos, ins):
+        return _merged_rank(
+            bounds, _xla(base), _xla(dpos), _xla(ins), steps=steps,
+            tsteps=tsteps, isteps=isteps,
         )
 
-    lr = jax.vmap(rank_one)(base_keys, live_prefix, ins_keys)  # (S, 2)
+    lr = jax.vmap(rank_one)(base_keys, dead_pos, ins_keys)  # (S, 2)
     ls0 = lr[:, 0]
     ls1 = jnp.maximum(lr[:, 1], ls0)  # inverted ranges clamp empty
     span = ls1 - ls0
     pre = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(span)])
     own_lo, own_hi = pre[:-1], pre[1:]
 
+    rows = (base_keys, base_vals, ins_keys, ins_vals, ins_rank, dead_rank,
+            ls0, own_lo, own_hi)
     if use_kernel:
         keys, vals, live = rmi_sharded_scan_page_pallas(
-            base_keys, base_vals, live_prefix, ins_keys, ins_vals,
-            ins_rank, ls0, own_lo, own_hi,
-            page_size=page_size, max_pages=max_pages, interpret=interpret,
+            *rows, page_size=page_size, max_pages=max_pages,
+            interpret=interpret,
         )
     else:
         keys, vals, live = ref.rmi_sharded_scan_page_reference(
-            base_keys, base_vals, live_prefix, ins_keys, ins_vals,
-            ins_rank, ls0, own_lo, own_hi,
-            page_size=page_size, max_pages=max_pages,
+            *rows, page_size=page_size, max_pages=max_pages,
         )
     # exactly one shard owns each stream slot: min/sum/max reassemble
     return (

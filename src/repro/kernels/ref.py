@@ -176,34 +176,37 @@ def rmi_scan_range_reference(
     bounds: jax.Array,             # (2,) f32 normalized [lo, hi)
     base_keys: jax.Array,          # (N,) sorted normalized f32
     base_vals: jax.Array,          # (N,) int32
-    live_prefix: jax.Array,        # (N+1,) i32 prefix-sum page index
     ins_keys: jax.Array,           # (D,) +inf-padded eff. insert keys
     ins_vals: jax.Array,           # (D,) int32
     ins_rank: jax.Array,           # (D,) i32 merged rank per insert
+    dead_pos: jax.Array,           # (T,) i32 sorted dead positions, big pad
+    dead_rank: jax.Array,          # (T,) i32 live rows below each, big pad
     *,
     page_size: int,
     max_pages: int,
 ) -> tuple:
     """XLA fallback for `rmi_scan_range_pallas`: the same endpoint
-    ranking (`_merged_rank_from_prefix`) and row resolution
-    (`_scan_rows_from_index`) evaluated on the full (G, page_size)
-    target matrix, so ``(keys, vals, live)`` is bit-identical to the
-    kernel's for every input — one fused XLA program, no host ranks.
+    ranking (`_merged_rank`) and row resolution (`_scan_rows`)
+    evaluated on the full (G, page_size) target matrix, so ``(keys,
+    vals, live)`` is bit-identical to the kernel's for every input —
+    one fused XLA program, no host ranks.
     """
     steps = rmi_lookup_lib._search_steps(base_keys.shape[0])
     isteps = rmi_lookup_lib._search_steps(ins_keys.shape[0])
-    psteps = rmi_lookup_lib._search_steps(base_keys.shape[0] + 1)
     msteps = rmi_lookup_lib._search_steps(ins_rank.shape[0])
-    base_keys, base_vals, live_prefix, ins_keys, ins_vals, ins_rank = (
+    tsteps = rmi_lookup_lib._search_steps(dead_pos.shape[0])
+    (base_keys, base_vals, ins_keys, ins_vals, ins_rank, dead_pos,
+     dead_rank) = (
         rmi_lookup_lib._xla(a) for a in
-        (base_keys, base_vals, live_prefix, ins_keys, ins_vals, ins_rank)
+        (base_keys, base_vals, ins_keys, ins_vals, ins_rank, dead_pos,
+         dead_rank)
     )
-    # the scopes (here and in `_scan_rows_from_index`) name the
-    # program's steps in its operations' metadata
+    # the scopes (here and in `_scan_rows`) name the program's steps in
+    # its operations' metadata
     with jax.named_scope("endpoint_search"):
-        r = rmi_lookup_lib._merged_rank_from_prefix(
-            bounds, base_keys, live_prefix, ins_keys,
-            steps=steps, isteps=isteps,
+        r = rmi_lookup_lib._merged_rank(
+            bounds, base_keys, dead_pos, ins_keys,
+            steps=steps, tsteps=tsteps, isteps=isteps,
         )
     r0 = r[0]
     r1 = jnp.maximum(r[1], r0)
@@ -212,19 +215,19 @@ def rmi_scan_range_reference(
     ) * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (max_pages, page_size), 1
     )
-    return rmi_lookup_lib._scan_rows_from_index(
-        t, t < r1, base_keys, base_vals, live_prefix, ins_keys,
-        ins_vals, ins_rank, psteps=psteps, msteps=msteps,
+    return rmi_lookup_lib._scan_rows(
+        t, t < r1, base_keys, base_vals, ins_keys, ins_vals, ins_rank,
+        dead_rank, msteps=msteps, tsteps=tsteps,
     )
 
 
 def rmi_sharded_scan_page_reference(
     base_keys: jax.Array,          # (S, N) sorted f32, +inf padded
     base_vals: jax.Array,          # (S, N) int32
-    live_prefix: jax.Array,        # (S, N+1) i32, pinned past true n
     ins_keys: jax.Array,           # (S, D) +inf padded
     ins_vals: jax.Array,           # (S, D) int32
     ins_rank: jax.Array,           # (S, D) i32, big pad
+    dead_rank: jax.Array,          # (S, T) i32, big pad
     ls0: jax.Array,                # (S,) i32
     own_lo: jax.Array,             # (S,) i32
     own_hi: jax.Array,             # (S,) i32
@@ -233,29 +236,29 @@ def rmi_sharded_scan_page_reference(
     max_pages: int,
 ) -> tuple:
     """XLA fallback for `rmi_sharded_scan_page_pallas`: the same
-    per-shard `_scan_rows_from_index` vmapped over the shard axis
-    instead of iterated by the kernel grid — bit-identical (S, G, P)
-    matrices, same owner-mask emission."""
-    psteps = rmi_lookup_lib._search_steps(base_keys.shape[1] + 1)
+    per-shard `_scan_rows` vmapped over the shard axis instead of
+    iterated by the kernel grid — bit-identical (S, G, P) matrices,
+    same owner-mask emission."""
     msteps = rmi_lookup_lib._search_steps(ins_rank.shape[1])
+    tsteps = rmi_lookup_lib._search_steps(dead_rank.shape[1])
     t_rel = jax.lax.broadcasted_iota(
         jnp.int32, (max_pages, page_size), 0
     ) * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (max_pages, page_size), 1
     )
 
-    def one_shard(base, bvals, lp, ins, ivals, irank, l0, olo, ohi):
+    def one_shard(base, bvals, ins, ivals, irank, drank, l0, olo, ohi):
         owner = (t_rel >= olo) & (t_rel < ohi)
         t_local = l0 + t_rel - olo
-        return rmi_lookup_lib._scan_rows_from_index(
+        return rmi_lookup_lib._scan_rows(
             t_local, owner,
             *(rmi_lookup_lib._xla(a)
-              for a in (base, bvals, lp, ins, ivals, irank)),
-            psteps=psteps, msteps=msteps,
+              for a in (base, bvals, ins, ivals, irank, drank)),
+            msteps=msteps, tsteps=tsteps,
         )
 
     return jax.vmap(one_shard)(
-        base_keys, base_vals, live_prefix, ins_keys, ins_vals, ins_rank,
+        base_keys, base_vals, ins_keys, ins_vals, ins_rank, dead_rank,
         ls0, own_lo, own_hi,
     )
 

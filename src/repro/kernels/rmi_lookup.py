@@ -329,52 +329,55 @@ def _scan_page_body(
     return key, val, live
 
 
-def _merged_rank_from_prefix(
+def _merged_rank(
     q,                           # f32 queries (any shape), normalized frame
     base_keys: _Flat,            # (N,) sorted f32, +inf past the true size
-    live_prefix: _Flat,          # (N+1,) i32 live base rows below position p
+    dead_pos: _Flat,             # (T,) sorted dead base positions, big pad
     ins_keys: _Flat,             # (D,) sorted eff. insert keys, +inf pad
     *,
     steps: int,
+    tsteps: int,
     isteps: int,
 ):
-    """Merged lower-bound rank straight from the prefix-sum page index:
+    """Merged lower-bound rank of each query:
 
-        rank(q) = live_prefix[lower_bound(base, q)] + lower_bound(ins, q)
+        bl = lower_bound(base, q)
+        rank(q) = bl - lower_bound(dead_pos, bl) + lower_bound(ins, q)
 
     — the device-side twin of `PinnedView.rank`, so scan endpoints never
-    round-trip through host NumPy.  ``live_prefix[p] = p - #tombstoned
-    positions < p`` is precomputed host-side per (snapshot, delta)
-    version; the two searches are fixed-trip and pad-safe (+inf pads
-    sort past every finite query)."""
+    round-trip through host NumPy.  The tombstone search is the size of
+    the delta, not of the base; every search is fixed-trip and pad-safe
+    (+inf key pads and the big position pad sort past every query)."""
     bl = _array_lower_bound(base_keys, q, base_keys.size, steps)
+    dead = _array_lower_bound(dead_pos, bl, dead_pos.size, tsteps)
     ins = _array_lower_bound(ins_keys, q, ins_keys.size, isteps)
-    return live_prefix.read(bl) + ins
+    return bl - dead + ins
 
 
-def _scan_rows_from_index(
+def _scan_rows(
     t,                           # int32 target merged ranks (any shape)
     valid,                       # bool: lanes that hold a live row
     base_keys: _Flat,            # (N,) sorted f32, +inf past the true size
     base_vals: _Flat,            # (N,) int32 payload aligned with base
-    live_prefix: _Flat,          # (N+1,) i32, pinned past the true size
     ins_keys: _Flat,             # (D,) sorted eff. insert keys, +inf pad
     ins_vals: _Flat,             # (D,) int32 staged values (0 on pads)
     ins_rank: _Flat,             # (D,) i32 merged rank of insert j, big pad
+    dead_rank: _Flat,            # (T,) i32 live base rows below dead j, big pad
     *,
-    psteps: int,
     msteps: int,
+    tsteps: int,
 ):
-    """One merged row per target rank, resolved entirely through the
-    precomputed prefix-sum page index — two single-read fixed-trip
-    searches per row instead of `_scan_page_body`'s nested
-    search-inside-search loops:
+    """One merged row per target rank, resolved through two searches
+    over delta-sized arrays (host-precomputed per delta version):
 
       1. partition:  j = lower_bound(ins_rank, t) — ``ins_rank[j] =
          j + live_base_before(ins[j])`` is the merged rank of staged
-         insert j, strictly increasing, HOST-precomputed;
-      2. select:     the (t-j)-th live base row via one lower bound
-         over the monotone ``live_prefix`` array;
+         insert j, strictly increasing;
+      2. select:     the a-th live base row, a = t - j, is at position
+         ``p = a + upper_bound(dead_rank, a)``: with ``dead_rank[k] =
+         dead_pos[k] - k`` the live rows below dead position k
+         (nondecreasing), exactly the dead positions whose
+         ``dead_rank`` is at most a lie below that row;
       3. emit        the insert row where its key is at most the base
          row's, else the base row, with its source's value; rows with
          ``valid`` False are masked dead (+inf key, 0 val).
@@ -388,11 +391,12 @@ def _scan_rows_from_index(
     inf = jnp.float32(jnp.inf)
     n, ni = base_keys.size, ins_keys.size
 
-    with jax.named_scope("live_prefix_search"):
+    with jax.named_scope("delta_search"):
         j = _array_lower_bound(ins_rank, t, ni, msteps)
         a_i = t - j
-        # smallest idx with live_prefix[idx] >= a_i + 1; row position idx-1
-        p = _array_lower_bound(live_prefix, a_i + 1, n + 1, psteps) - 1
+        # dead_rank holds integers: upper_bound(a) = lower_bound(a + 1)
+        p = a_i + _array_lower_bound(dead_rank, a_i + 1, dead_rank.size,
+                                     tsteps)
 
     with jax.named_scope("row_gather"):
         a_key = jnp.where(
@@ -790,16 +794,17 @@ def rmi_scan_page_pallas(
     return _unpage(outs, (g,), row, page_size)
 
 
-def _scan_range_kernel(bounds_ref, bk, bv, lp, ik, iv, ir, *outs, sizes,
-                       page_size, steps, isteps, psteps, msteps):
-    n, d = sizes
+def _scan_range_kernel(bounds_ref, bk, bv, ik, iv, ir, dp, dr, *outs,
+                       sizes, page_size, steps, isteps, msteps, tsteps):
+    n, d, nt = sizes
     base_keys, base_vals = _slab(bk, n), _slab(bv, n)
-    live_prefix = _slab(lp, n + 1)
     ins_keys, ins_vals, ins_rank = _slab(ik, d), _slab(iv, d), _slab(ir, d)
+    dead_pos, dead_rank = _slab(dp, nt), _slab(dr, nt)
 
     def rank(x):
-        return _merged_rank_from_prefix(
-            x, base_keys, live_prefix, ins_keys, steps=steps, isteps=isteps,
+        return _merged_rank(
+            x, base_keys, dead_pos, ins_keys, steps=steps, tsteps=tsteps,
+            isteps=isteps,
         )
 
     r0 = rank(bounds_ref[0])
@@ -808,9 +813,9 @@ def _scan_range_kernel(bounds_ref, bk, bv, lp, ik, iv, ir, *outs, sizes,
 
     def row(l):
         t = first + l
-        return _scan_rows_from_index(
-            t, t < r1, base_keys, base_vals, live_prefix, ins_keys,
-            ins_vals, ins_rank, psteps=psteps, msteps=msteps,
+        return _scan_rows(
+            t, t < r1, base_keys, base_vals, ins_keys, ins_vals, ins_rank,
+            dead_rank, msteps=msteps, tsteps=tsteps,
         )
 
     _emit_rows(page_size, outs, row)
@@ -823,10 +828,11 @@ def rmi_scan_range_pallas(
     bounds: jax.Array,             # (2,) f32 normalized [lo, hi)
     base_keys: jax.Array,          # (N,) sorted normalized f32
     base_vals: jax.Array,          # (N,) int32
-    live_prefix: jax.Array,        # (N+1,) i32 prefix-sum page index
     ins_keys: jax.Array,           # (D,) +inf-padded eff. insert keys
     ins_vals: jax.Array,           # (D,) int32
     ins_rank: jax.Array,           # (D,) i32 merged rank of each insert
+    dead_pos: jax.Array,           # (T,) i32 sorted dead positions, big pad
+    dead_rank: jax.Array,          # (T,) i32 live rows below each, big pad
     *,
     page_size: int,
     max_pages: int,
@@ -838,49 +844,49 @@ def rmi_scan_range_pallas(
     round-trip between ranking and gathering.  Grid = pages
     (``max_pages`` is the caller's conservative static bound; pages
     past ``r1`` come back fully masked).  Rank-to-row resolution runs
-    through the precomputed prefix-sum page index (`live_prefix`,
-    ``ins_rank``), so each row costs two single-read fixed-trip
-    searches — the nested tombstone searches of `rmi_scan_page_pallas`
-    are hoisted to host precompute, amortized across every scan of a
-    (snapshot, delta) version."""
+    through the delta-sized arrays the host precomputes per delta
+    version (``ins_rank``, ``dead_pos``, ``dead_rank``), so each row
+    costs two single-read fixed-trip searches over the delta — the
+    nested tombstone searches of `rmi_scan_page_pallas` are hoisted to
+    that precompute."""
     g = max_pages
-    n, d = base_keys.shape[0], ins_keys.shape[0]
+    n, d, nt = base_keys.shape[0], ins_keys.shape[0], dead_pos.shape[0]
     row, out_specs, out_shape = _page_outputs(g, page_size, lambda i: (i,))
     outs = pl.pallas_call(
         functools.partial(
-            _scan_range_kernel, sizes=(n, d), page_size=page_size,
+            _scan_range_kernel, sizes=(n, d, nt), page_size=page_size,
             steps=_search_steps(n), isteps=_search_steps(d),
-            psteps=_search_steps(n + 1),
             msteps=_search_steps(ins_rank.shape[0]),
+            tsteps=_search_steps(nt),
         ),
         grid=(g,),
-        in_specs=[_smem()] + [_vmem()] * 6,
+        in_specs=[_smem()] + [_vmem()] * 7,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=resolve_interpret(interpret),
     )(bounds, _rows(base_keys, jnp.inf), _rows(base_vals),
-      _rows(live_prefix), _rows(ins_keys, jnp.inf), _rows(ins_vals),
-      _rows(ins_rank))
+      _rows(ins_keys, jnp.inf), _rows(ins_vals), _rows(ins_rank),
+      _rows(dead_pos), _rows(dead_rank))
     return _unpage(outs, (g,), row, page_size)
 
 
-def _sharded_scan_kernel(bk, bv, lp, ik, iv, ir, ls0_ref, own_lo_ref,
-                         own_hi_ref, *outs, sizes, page_size, psteps,
-                         msteps):
+def _sharded_scan_kernel(bk, bv, ik, iv, ir, dr, ls0_ref, own_lo_ref,
+                         own_hi_ref, *outs, sizes, page_size, msteps,
+                         tsteps):
     si, gi = pl.program_id(0), pl.program_id(1)
-    n, d = sizes
+    n, d, nt = sizes
     flats = (_slab(bk, n, (0,)), _slab(bv, n, (0,)),
-             _slab(lp, n + 1, (0,)), _slab(ik, d, (0,)),
-             _slab(iv, d, (0,)), _slab(ir, d, (0,)))
+             _slab(ik, d, (0,)), _slab(iv, d, (0,)),
+             _slab(ir, d, (0,)), _slab(dr, nt, (0,)))
     own_lo, own_hi, ls0 = own_lo_ref[si], own_hi_ref[si], ls0_ref[si]
     first = gi * page_size
 
     def row(l):
         t_rel = first + l
         owner = (t_rel >= own_lo) & (t_rel < own_hi)
-        return _scan_rows_from_index(
-            ls0 + t_rel - own_lo, owner, *flats, psteps=psteps,
-            msteps=msteps,
+        return _scan_rows(
+            ls0 + t_rel - own_lo, owner, *flats, msteps=msteps,
+            tsteps=tsteps,
         )
 
     _emit_rows(page_size, outs, row)
@@ -892,10 +898,10 @@ def _sharded_scan_kernel(bk, bv, lp, ik, iv, ir, ls0_ref, own_lo_ref,
 def rmi_sharded_scan_page_pallas(
     base_keys: jax.Array,          # (S, N) sorted f32, +inf padded
     base_vals: jax.Array,          # (S, N) int32, 0 padded
-    live_prefix: jax.Array,        # (S, N+1) i32, pinned past true n
     ins_keys: jax.Array,           # (S, D) +inf-padded eff. inserts
     ins_vals: jax.Array,           # (S, D) int32
     ins_rank: jax.Array,           # (S, D) i32, big pad
+    dead_rank: jax.Array,          # (S, T) i32, big pad
     ls0: jax.Array,                # (S,) i32 local rank of lo per shard
     own_lo: jax.Array,             # (S,) i32 shard's first output rank
     own_hi: jax.Array,             # (S,) i32 one past its last
@@ -913,24 +919,24 @@ def rmi_sharded_scan_page_pallas(
     the same jitted program by `ops.rmi_sharded_scan_page_op`'s rank
     pre-pass) say which slice of the output stream each shard owns.
     Every (shard, page) grid step resolves the page's target ranks
-    against its own slab through the per-shard prefix-sum page index;
-    non-owned rows emit (+inf, 0, dead), so reducing min/sum/max over
-    the shard axis reassembles the global pages.  Returns the raw
-    (S, G, P) per-shard matrices; the op does the reduction."""
+    against its own slab through the per-shard staged-insert and
+    tombstone ranks; non-owned rows emit (+inf, 0, dead), so reducing
+    min/sum/max over the shard axis reassembles the global pages.
+    Returns the raw (S, G, P) per-shard matrices; the op does the
+    reduction."""
     s, n = base_keys.shape
     g = max_pages
-    d = ins_keys.shape[1]
+    d, nt = ins_keys.shape[1], dead_rank.shape[1]
     row, out_specs, out_shape = _page_outputs(
         s * g, page_size, lambda si, gi: (si * g + gi,)
     )
     slabs = [_rows(base_keys, jnp.inf), _rows(base_vals),
-             _rows(live_prefix), _rows(ins_keys, jnp.inf),
-             _rows(ins_vals), _rows(ins_rank)]
+             _rows(ins_keys, jnp.inf), _rows(ins_vals), _rows(ins_rank),
+             _rows(dead_rank)]
     outs = pl.pallas_call(
         functools.partial(
-            _sharded_scan_kernel, sizes=(n, d), page_size=page_size,
-            psteps=_search_steps(n + 1),
-            msteps=_search_steps(ins_rank.shape[1]),
+            _sharded_scan_kernel, sizes=(n, d, nt), page_size=page_size,
+            msteps=_search_steps(d), tsteps=_search_steps(nt),
         ),
         grid=(s, g),
         in_specs=[_shard_rows(a) for a in slabs] + [_smem()] * 3,

@@ -38,6 +38,7 @@ from repro.index_service.compact import Compactor
 from repro.index_service.delta import DeltaBuffer
 from repro.index_service.router import LearnedRouter
 from repro.index_service.scan import (
+    SLAB_ROWS,
     PinnedView,
     pin_view,
     repack_pages,
@@ -320,12 +321,7 @@ class PagedKVAllocator:
             "ins_total": slabs["ins_total"],
             # fresh arrays per build: plain asarray upload is safe here
             # (no in-place mirror mutation like the sharded plane)
-            "base": jnp.asarray(slabs["base"]),
-            "bvals": jnp.asarray(slabs["bvals"]),
-            "live_prefix": jnp.asarray(slabs["live_prefix"]),
-            "ins": jnp.asarray(slabs["ins"]),
-            "ivals": jnp.asarray(slabs["ivals"]),
-            "ins_rank": jnp.asarray(slabs["ins_rank"]),
+            "slab": tuple(jnp.asarray(slabs[k]) for k in SLAB_ROWS),
         }
         self._scan_plane_cache = plane
         return plane
@@ -357,9 +353,8 @@ class PagedKVAllocator:
         use_kernel = self.strategy in ("pallas", "pallas_fused",
                                        "sharded_fused")
         return kernels_ops.rmi_sharded_scan_page_op(
-            bounds, plane["base"], plane["bvals"], plane["live_prefix"],
-            plane["ins"], plane["ivals"], plane["ins_rank"],
-            page_size=page_size, max_pages=pages, use_kernel=use_kernel,
+            bounds, *plane["slab"], page_size=page_size, max_pages=pages,
+            use_kernel=use_kernel,
         )
 
     def scan_normalize(self, keys) -> np.ndarray:

@@ -407,7 +407,7 @@ def test_insert_then_scan_updates_the_plane_in_a_span(tmp_path):
     (update,) = [s for s in spans if s[0] == "plane.scan.update"]
     (prep,) = [s for s in spans if s[0] == "service.prepare"]
     assert _inside(update, prep) and update[4]["staged"] == 2
-    # the page index was kept: no full build in the trace
+    # the snapshot was kept: no rebuild in the trace
     assert not [s for s in spans if s[0] == "scan.pack_slab"]
     assert svc.metrics.counter("plane.scan.updates").value == 1
     assert svc.metrics.counter("plane.scan.rebuilds").value == 1
@@ -447,16 +447,16 @@ def _lookup_program(svc, base):
 def _scan_program(svc, base):
     from repro.kernels import ops
 
-    snap, (ins, ivals, ins_rank, lp), _ = svc._scan_plane_cached()
+    snap, slab, _ = svc._scan_plane_cached()
     bounds = jnp.asarray(snap.keys.normalize(base[[3, 90]]), jnp.float32)
     return ops._scan_range_jit.lower(
-        bounds, *snap._device_base(), lp, ins, ivals, ins_rank,
+        bounds, *snap._device_base(), *slab,
         page_size=64, max_pages=2, use_kernel=False, interpret=None)
 
 
 @pytest.mark.parametrize("program,scopes", [
     (_lookup_program, ("base_search", "delta_search", "prefix_gather")),
-    (_scan_program, ("endpoint_search", "live_prefix_search", "row_gather")),
+    (_scan_program, ("endpoint_search", "delta_search", "row_gather")),
 ])
 def test_device_programs_name_their_steps(program, scopes):
     """Each step of the served device programs carries a named scope in

@@ -294,10 +294,9 @@ def _fused_scan_inputs(op):
     snap = svc._mgr.current()
     base_norm, bvals = (np.asarray(a) for a in snap._device_base())
     if op == "range":
-        _, (ins, ivals, ins_rank, lp), _ = svc._scan_plane_cached()
+        _, slab, _ = svc._scan_plane_cached()
         args = (snap.keys.normalize(np.array([lo, hi])), base_norm, bvals,
-                np.asarray(lp), np.asarray(ins), np.asarray(ivals),
-                np.asarray(ins_rank))
+                *(np.asarray(a) for a in slab))
         static = dict(page_size=64, max_pages=6)  # the last page: masked
         return ops.rmi_scan_range_op, args, static, (
             ref.rmi_scan_range_reference)
@@ -629,13 +628,14 @@ def test_rank_addressed_scan_pages_merge_inserts_that_tie(use_kernel):
 def _plane_steps(seed):
     """A seeded sequence of write versions: inserts between them, one
     delete of base keys, one delete of staged keys only, one freeze,
-    one compaction; with the plane path each is expected to take."""
+    one compaction; with the plane path each is expected to take: only
+    the compaction's new snapshot rebuilds."""
     rng = np.random.default_rng(seed)
     steps = [("insert", "update") for _ in range(int(rng.integers(2, 5)))]
     steps.insert(int(rng.integers(1, len(steps) + 1)),
-                 ("delete base", "rebuild"))
+                 ("delete base", "update"))
     steps += [("delete staged", "update"), ("insert", "update"),
-              ("freeze", "rebuild"), ("insert", "update")]
+              ("freeze", "update"), ("insert", "update")]
     steps += [("insert", "update")] * int(rng.integers(1, 3))
     steps += [("compact", "rebuild"), ("insert", "update")]
     return steps
@@ -644,11 +644,14 @@ def _plane_steps(seed):
 @pytest.mark.parametrize("seed", [3, 2**31 + 19, 2**33 + 5])
 def test_incremental_scan_plane_matches_a_full_build(seed):
     """After every write version the plane the service serves equals a
-    from-scratch `device_scan_slab` of the same view bit for bit, and
-    its counters count exactly the full builds and the updates: a write
-    that leaves the snapshot and the tombstones alone only re-packs the
-    staged inserts."""
-    from repro.index_service.scan import device_scan_slab, pin_view
+    from-scratch packing of the same view bit for bit, and its counters
+    count exactly the rebuilds and the updates: every write version of
+    one snapshot, tombstones and freezes included, is an update."""
+    from repro.index_service.scan import (
+        pin_view,
+        staged_scan_arrays,
+        tombstone_scan_arrays,
+    )
 
     rng = np.random.default_rng(seed)
     base = np.unique(rng.integers(0, 1 << 40, 3000).astype(np.float64))
@@ -680,9 +683,11 @@ def test_incremental_scan_plane_matches_a_full_build(seed):
         want[path + "s"] += 1
         snap, slab, imgs = svc._scan_plane_cached()
         view = pin_view(snap, svc._frozen, svc._active)
-        full = device_scan_slab(
+        full = staged_scan_arrays(
             view, snap.keys.norm, snap.keys.normalize,
-            min_pad=plane.staged_min_pad(view.ins_keys.size))
+            min_pad=plane.staged_min_pad(view.ins_keys.size),
+        ) + tombstone_scan_arrays(
+            view.del_pos, min_pad=plane.staged_min_pad(view.del_pos.size))
         for got, ref_arr in zip(slab, full):
             got = np.asarray(got)
             assert got.dtype == ref_arr.dtype and got.shape == ref_arr.shape
@@ -716,3 +721,280 @@ def test_non_empty_delta_scans_at_one_pad():
         pads.add((k > 0, ins.shape[0]))
     assert pads == {(False, 64), (True, svc._plane.staged_pad)}
     assert svc._plane.staged_pad >= 2 * 512 + 1
+
+
+# --------------------------------------------------------------------------
+# the rank <-> row map through the tombstones
+# --------------------------------------------------------------------------
+
+def _dead_positions(case, rng, n):
+    """Sorted dead base positions of one shape of tombstone set."""
+    if case == "none":
+        return np.empty(0, np.int64)
+    if case == "random":
+        return np.sort(rng.choice(n, int(rng.integers(1, n // 3)),
+                                  replace=False))
+    if case == "runs":
+        starts = rng.choice(n - 12, 6, replace=False)
+        run = [s + np.arange(int(rng.integers(1, 12))) for s in starts]
+        return np.unique(np.concatenate(run + [[0, 1, 2]]))
+    if case == "ends":
+        return np.unique(np.concatenate(
+            [[0, n - 1], rng.choice(n, 5, replace=False)]))
+    return np.arange(n)   # everything dead
+
+
+def _live_prefix_rows(t, base, bvals, ins, ivals, ins_rank, dead):
+    """NumPy reference of the rank -> row map through the (n+1)-entry
+    live-prefix index, ``live_prefix[p] = p - #dead positions < p``:
+    the partition ``j = lower_bound(ins_rank, t)`` and the ``(t - j)``-th
+    live base row at ``lower_bound(live_prefix, t - j + 1) - 1``, a
+    staged insert emitted where its key is at most the base row's."""
+    n, ni = base.size, ins.size
+    mark = np.zeros(n + 1, np.int64)
+    mark[dead + 1] = 1
+    live_prefix = np.arange(n + 1) - np.cumsum(mark)
+    j = np.searchsorted(ins_rank, t, side="left")
+    p = np.searchsorted(live_prefix, t - j + 1, side="left") - 1
+    inside = (p >= 0) & (p < n)
+    a_key = np.where(inside, base[np.clip(p, 0, n - 1)], np.inf)
+    a_val = bvals[np.clip(p, 0, n - 1)]
+    c_key = np.where(j < ni, ins[np.clip(j, 0, ni - 1)], np.inf)
+    c_val = ivals[np.clip(j, 0, ni - 1)]
+    from_ins = c_key <= a_key
+    return (np.where(from_ins, c_key, a_key).astype(np.float32),
+            np.where(from_ins, c_val, a_val).astype(np.int32),
+            live_prefix)
+
+
+@pytest.mark.parametrize("staged", [0, 9])
+@pytest.mark.parametrize("case", ["none", "random", "runs", "ends", "all"])
+def test_tombstone_search_maps_ranks_like_the_live_prefix(case, staged):
+    """The delta-sized tombstone arrays rank the scan endpoints and
+    select each rank's row exactly as the (n+1)-entry live-prefix index
+    did, for every rank: tombstone runs, positions 0 and n - 1, every
+    base row dead, ranks before the first row and past the last, with
+    and without staged inserts; and the live rows are the plain
+    merge."""
+    import jax
+
+    from repro.index_service.scan import (
+        PinnedView,
+        staged_scan_arrays,
+        tombstone_scan_arrays,
+    )
+    from repro.kernels import rmi_lookup as rl
+
+    rng = np.random.default_rng(
+        ["none", "random", "runs", "ends", "all"].index(case) * 10 + staged)
+    n = 200
+    # keys 4k and staged 4k + 2 below 4096: exact float32 images, no ties
+    base = np.sort(rng.choice(1024, n, replace=False)) * 4.0
+    bvals = np.arange(n, dtype=np.int32) * 3
+    ins = np.sort(rng.choice(1024, staged, replace=False)) * 4.0 + 2.0
+    dead = _dead_positions(case, rng, n)
+    view = PinnedView(base_keys=base, base_vals=bvals.astype(np.int64),
+                      ins_keys=ins, ins_vals=1000 + np.arange(staged),
+                      del_pos=dead.astype(np.int64))
+
+    def normalize(x):
+        return (np.asarray(x, np.float64) / 4096.0).astype(np.float32)
+
+    base_norm = normalize(base)
+    ins_p, ivals_p, ins_rank = staged_scan_arrays(view, base_norm,
+                                                  normalize)
+    dead_pos, dead_rank = tombstone_scan_arrays(view.del_pos)
+    assert dead_pos.shape == dead_rank.shape and dead_pos.size < 4 * (
+        dead.size + 64)
+    total = view.live_count
+    t = np.arange(-3, total + 6, dtype=np.int32)
+    want_k, want_v, live_prefix = _live_prefix_rows(
+        t, base_norm, bvals, ins_p, ivals_p, ins_rank, dead)
+    valid = (t >= 0) & (t < total)
+    want_k = np.where(valid, want_k, np.inf).astype(np.float32)
+    want_v = np.where(valid, want_v, 0).astype(np.int32)
+
+    step = rl._search_steps
+
+    @jax.jit
+    def device_map(q, t, valid):
+        flats = [rl._xla(a) for a in (base_norm, bvals, ins_p, ivals_p,
+                                      ins_rank, dead_pos, dead_rank)]
+        b, bv, i, iv, ir, dp, dr = flats
+        rank = rl._merged_rank(q, b, dp, i, steps=step(n),
+                               tsteps=step(dp.size), isteps=step(i.size))
+        rows = rl._scan_rows(t, valid, b, bv, i, iv, ir, dr,
+                             msteps=step(ir.size), tsteps=step(dr.size))
+        return rank, rows
+
+    # endpoints: every stored key, a key's neighbours, before the first
+    # key and past the last
+    q = normalize(np.concatenate([base, ins, base + 1.0, base - 1.0,
+                                  [-8.0, 4095.0, 5000.0]]))
+    rank, (keys, vals, live) = (
+        jax.tree_util.tree_map(np.asarray, device_map(q, t, valid)))
+    want_rank = (live_prefix[np.searchsorted(base_norm, q, side="left")]
+                 + np.searchsorted(ins_p[:staged], q, side="left"))
+    np.testing.assert_array_equal(rank, want_rank)
+    np.testing.assert_array_equal(keys, want_k)
+    np.testing.assert_array_equal(vals, want_v)
+    np.testing.assert_array_equal(live, valid.astype(np.int32))
+    # the reference itself: the live rows are the sorted merge
+    alive = np.setdiff1d(np.arange(n), dead)
+    merged = np.sort(np.concatenate([base_norm[alive], ins_p[:staged]]))
+    np.testing.assert_array_equal(keys[valid], merged)
+
+
+@pytest.mark.parametrize("plan", ["runs", "ends", "most dead"])
+def test_fused_scan_kernel_matches_xla_over_tombstones(plan):
+    """The fused scan's Pallas kernel (interpret mode off-TPU) and its
+    XLA body return the same rows bit for bit over plans that hold
+    tombstones — runs, the first and last base rows, all but a few
+    rows dead — for ranges inside, across and past the live rows, and
+    both equal the plain merge in the float32 frame."""
+    base = np.arange(2, 602, dtype=np.float64) * 1024.0
+    rng = np.random.default_rng(["runs", "ends", "most dead"].index(plan))
+    dead = {"runs": np.concatenate([base[40:90], base[300:305],
+                                    base[::97]]),
+            "ends": base[[0, 1, -2, -1]],
+            "most dead": np.delete(base, [3, 250, 251, 599])}[plan]
+    ins = np.setdiff1d(
+        np.unique(rng.integers(2, 602, 40)) * 1024.0 + 512.0, base)
+    ranges = [(base[0], base[-1] + 1.0), (base[35], base[95]),
+              (base[-3], base[-1] * 2.0), (base[500], base[100])]
+    svcs = {}
+    for strategy in ("pallas_fused", "binary"):
+        svc = IndexService(base, ServiceConfig(delta_capacity=2048,
+                                               strategy=strategy),
+                           vals=np.arange(base.size) * 7)
+        svc.insert(ins, np.arange(ins.size) + 50_000)
+        svc.delete(dead)
+        svcs[strategy] = svc
+    snap = svcs["binary"]._mgr.current()
+    alive = ~np.isin(base, dead)
+    for lo, hi in ranges:
+        a, b = ([np.asarray(x) for x in svcs[s].scan_batch(lo, hi, 64)]
+                for s in ("pallas_fused", "binary"))
+        for x, y in zip(a, b):
+            np.testing.assert_array_equal(x, y)
+        want_k, want_v = _frame_merge(
+            snap, base[alive], (np.arange(base.size) * 7)[alive], ins,
+            np.arange(ins.size) + 50_000, lo, hi)
+        np.testing.assert_array_equal(a[0][a[2]], want_k)
+        np.testing.assert_array_equal(a[1][a[2]], want_v)
+
+
+def _plane_scan_matches_oracle(svc, live, lo, hi, normalize):
+    keys, vals, mask = (np.asarray(a) for a in svc.scan_batch(lo, hi, 128))
+    want_k, want_v = _oracle_slice(live, lo, hi)
+    np.testing.assert_array_equal(keys[mask], normalize(want_k))
+    np.testing.assert_array_equal(vals[mask], want_v.astype(np.int32))
+
+
+def test_deletes_freezes_and_swaps_repack_the_plane_in_delta_size():
+    """A delete of base keys, a freeze and a snapshot swap each re-pack
+    the scan plane from arrays the size of the delta: the plane counts
+    the dead positions it packed, its upload grows by a few KiB, no
+    device array of it is sized by the key count, and every scan
+    answers as the NumPy merge."""
+    n = 20_000
+    base = np.arange(2, n + 2, dtype=np.float64) * 1024.0
+    svc = IndexService(base, ServiceConfig(delta_capacity=256,
+                                           max_delta_levels=2),
+                       vals=np.arange(n) * 3)
+    live = dict(zip(base.tolist(), (np.arange(n) * 3).tolist()))
+    plane, reg = svc._plane, svc.metrics
+    lo, hi = float(base[10]), float(base[n - 10])
+
+    def count(name):
+        return reg.counter(f"plane.scan.{name}").value
+
+    def step(write, path, dead):
+        before = {k: count(k) for k in ("updates", "rebuilds",
+                                        "tombstones", "upload_bytes")}
+        write()
+        snap, slab, _ = svc._scan_plane_cached()
+        assert all(a.size < n // 8 for a in slab)
+        assert count(path + "s") == before[path + "s"] + 1
+        assert count("tombstones") - before["tombstones"] == dead
+        sent = count("upload_bytes") - before["upload_bytes"]
+        assert sent == sum(a.nbytes for a in slab)
+        assert sent <= 20 * plane.staged_pad
+        _plane_scan_matches_oracle(svc, live, lo, hi, snap.keys.normalize)
+
+    step(lambda: None, "rebuild", 0)   # the first build
+    doomed = np.concatenate([base[:3], base[500:540], base[[n - 1]]])
+
+    def delete():
+        svc.delete(doomed)
+        for k in doomed.tolist():
+            del live[k]
+    step(delete, "update", doomed.size)
+    fresh = base[:5] + 512.0
+
+    def insert():
+        svc.insert(fresh, np.arange(5) + 90_000)
+        live.update(zip(fresh.tolist(), (np.arange(5) + 90_000).tolist()))
+    step(insert, "update", doomed.size)
+    def freeze():
+        assert svc.maybe_compact() and svc.num_delta_levels == 1
+    step(freeze, "update", doomed.size)
+    step(lambda: svc.flush(), "rebuild", 0)
+
+
+def test_sharded_and_page_table_planes_keep_tombstones_in_delta_rows():
+    """The stacked planes of the sharded service (K=3) and of the KV page
+    table hold their tombstones in (S, delta pad) rows: a delete or a
+    freeze re-uploads only those rows and the staged ones, never the
+    base keys, and every scan answers as the NumPy merge."""
+    from repro.index_service.scan import SLAB_ROWS
+    from repro.serve.kvcache import PagedKVAllocator
+
+    base = np.arange(2, 9_002, dtype=np.float64) * 1024.0
+    svc = ShardedIndexService(
+        base, ServiceConfig(num_shards=3, delta_capacity=1024,
+                            max_delta_levels=2),
+        vals=np.arange(base.size) * 5)
+    live = dict(zip(base.tolist(), (np.arange(base.size) * 5).tolist()))
+    lo, hi = float(base[5]), float(base[-5])
+    _plane_scan_matches_oracle(svc, live, lo, hi, svc.scan_normalize)
+    first = svc._scan_plane()
+    doomed = np.concatenate([base[:2], base[2990:3020], base[[-1]]])
+    svc.delete(doomed)
+    for k in doomed.tolist():
+        del live[k]
+    for write in ("delete", "freeze", "swap"):
+        if write == "freeze":
+            for shard in svc._shards:
+                assert shard.maybe_compact()
+                assert shard.num_delta_levels == 1
+        elif write == "swap":
+            svc.flush()
+        plane = svc._scan_plane()
+        pad = plane.dev["dead_pos"].shape
+        assert pad == plane.dev["dead_rank"].shape == plane.dev["ins"].shape
+        assert pad[0] == 3 and pad[1] < 9_000 // 8
+        dead = (plane.host["dead_pos"] < (1 << 30)).sum()
+        assert dead == (doomed.size if write != "swap" else 0)
+        if write != "swap":
+            assert plane.dev["base"] is first.dev["base"]
+        _plane_scan_matches_oracle(svc, live, lo, hi, svc.scan_normalize)
+
+    alloc = PagedKVAllocator(num_pages=2048, page_size=16,
+                             delta_capacity=128, num_shards=4)
+    for uid in range(120):
+        alloc.alloc(uid, 48)
+    alloc.rebuild_index()
+    for uid in range(0, 120, 3):
+        alloc.free(uid)
+    slab = dict(zip(SLAB_ROWS, alloc._scan_plane()["slab"]))
+    assert slab["dead_pos"].shape[0] == 4
+    dead = int((np.asarray(slab["dead_pos"]) < (1 << 30)).sum())
+    assert dead == 40 * 3   # three pages of each freed request
+    lo, hi = 0.0, float(1 << 60)
+    keys, vals, mask = (np.asarray(a)
+                        for a in alloc.scan_batch(lo, hi, 64))
+    table = sorted(alloc._table.items())
+    np.testing.assert_array_equal(
+        keys[mask], alloc.scan_normalize(np.array([k for k, _ in table])))
+    np.testing.assert_array_equal(vals[mask], [v for _, v in table])

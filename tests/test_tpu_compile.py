@@ -110,7 +110,8 @@ def test_sharded_merged_lookup_compiles(one_chip, batch):
 
 def test_scan_range_compiles(one_chip):
     args = _shapes(one_chip, ((2,), F32), ((N,), F32), ((N,), I32),
-                   ((N + 1,), I32), ((D,), F32), ((D,), I32), ((D,), I32))
+                   ((D,), F32), ((D,), I32), ((D,), I32), ((D,), I32),
+                   ((D,), I32))
     assert _compiled_kernel(
         rmi_lookup.rmi_scan_range_pallas, args,
         dict(page_size=PAGE, max_pages=PAGES))
@@ -132,7 +133,7 @@ def test_fused_scan_op_program_compiles(one_chip, op):
     if op == "range":
         fn, static = ops._scan_range_jit, dict(max_pages=PAGES)
         args = _shapes(one_chip, ((2,), F32), ((N,), F32), ((N,), I32),
-                       ((N + 1,), I32), ((D,), F32), ((D,), I32),
+                       ((D,), F32), ((D,), I32), ((D,), I32), ((D,), I32),
                        ((D,), I32))
     else:
         fn, static = ops._scan_page_jit, {}
@@ -148,7 +149,9 @@ def test_cells_scan_program_compiles_at_their_size(one_chip, staged):
     """The scan program the benchmark's cells run (strategy ``binary``:
     the XLA body) over 200M keys, at the staged-insert pad of an empty
     delta and at the one pad every non-empty delta of a default service
-    scans at."""
+    scans at, with no tombstones (the cells delete nothing).  Its
+    arguments are the base keys and values and arrays of the delta's
+    size: nothing else sized by the key count."""
     from repro.index_service.plane import EMPTY_STAGED_PAD
     from repro.index_service.service import ServiceConfig, _staged_scan_pad
     from repro.kernels import ops
@@ -156,17 +159,23 @@ def test_cells_scan_program_compiles_at_their_size(one_chip, staged):
     n = 200_000_000
     d = (EMPTY_STAGED_PAD if staged == "empty"
          else _staged_scan_pad(ServiceConfig()))
+    t = EMPTY_STAGED_PAD    # tombstone pad
     args = _shapes(one_chip, ((2,), F32), ((n,), F32), ((n,), I32),
-                   ((n + 1,), I32), ((d,), F32), ((d,), I32), ((d,), I32))
-    ops._scan_range_jit.lower(
+                   ((d,), F32), ((d,), I32), ((d,), I32), ((t,), I32),
+                   ((t,), I32))
+    compiled = ops._scan_range_jit.lower(
         *args, page_size=PAGE, max_pages=2, use_kernel=False,
         interpret=False).compile()
+    # 8 B a key, and under a MiB more (the chip's tiling pads the
+    # delta-sized arrays): no operand of 4 B a key besides the base's
+    held = compiled.memory_analysis().argument_size_in_bytes
+    assert 8 * n <= held < 8 * n + (1 << 20)
 
 
 def test_sharded_scan_page_compiles(one_chip):
     ns = N // S
     args = _shapes(one_chip, ((S, ns), F32), ((S, ns), I32),
-                   ((S, ns + 1), I32), ((S, D), F32), ((S, D), I32),
+                   ((S, D), F32), ((S, D), I32), ((S, D), I32),
                    ((S, D), I32), ((S,), I32), ((S,), I32), ((S,), I32))
     assert _compiled_kernel(
         rmi_lookup.rmi_sharded_scan_page_pallas, args,
